@@ -1,6 +1,7 @@
 """Benchmark runner: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV.  Sections:
+Prints ``name,us_per_call,derived`` CSV (``--quick``:
+``name,platform,device_kind,cpu_us_per_call,derived``).  Sections:
   * paper_figures   — Figs 10-18 + §III message-count tables
   * gradsync        — gradient-sync schedule comparison (training buckets)
   * roofline_report — per-(arch x shape) roofline terms, if dry-run
@@ -27,6 +28,8 @@ for p in (str(_ROOT), str(_ROOT / "src")):
 
 def quick_smoke() -> int:
     """Execute one NAP + one MLA allreduce on a virtual CPU mesh."""
+    # virtual devices exist only on the CPU backend: never time a chip here
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         "--xla_force_host_platform_device_count=8 "
         + os.environ.get("XLA_FLAGS", "")
@@ -39,14 +42,16 @@ def quick_smoke() -> int:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.core import collectives
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((2, 4), ("pod", "data"))
     rng = np.random.default_rng(0)
     failures = 0
-    print("name,us_per_call,derived")
+    # every row names the device it ran on; the time is XLA:CPU's
+    dev = jax.devices()[0]
+    where = f"{dev.platform},{dev.device_kind}"
+    print("name,platform,device_kind,cpu_us_per_call,derived")
     cases = [
         ("nap", 8, {}),
         ("mla", 1 << 16, {}),
@@ -56,7 +61,7 @@ def quick_smoke() -> int:
     for algo, size, kw in cases:
         xs = jnp.asarray(rng.normal(size=(8, size)).astype(np.float32))
         fn = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 partial(
                     collectives.ALGORITHMS[algo],
                     inter_axes="pod",
@@ -80,7 +85,7 @@ def quick_smoke() -> int:
         jax.block_until_ready(out)
         us = (time.perf_counter() - t0) / iters * 1e6
         print(
-            f"quick_{algo}_s{size * 4},{us:.3f},"
+            f"quick_{algo}_s{size * 4},{where},{us:.3f},"
             f"{'ok' if ok else 'MISMATCH'}"
         )
 
@@ -103,10 +108,11 @@ def quick_smoke() -> int:
             return ctx.sync_grads({"w": x})["w"]
 
         fn = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 f, mesh=mesh,
                 in_specs=P(("pod", "data")),
                 out_specs=P(("pod", "data")),
+                check_vma=False,
             )
         )
         got = np.asarray(fn(xs))
@@ -122,7 +128,7 @@ def quick_smoke() -> int:
         jax.block_until_ready(out)
         us = (time.perf_counter() - t0) / iters * 1e6
         print(
-            f"quick_compressed_int{bits}_s{size * 4},{us:.3f},"
+            f"quick_compressed_int{bits}_s{size * 4},{where},{us:.3f},"
             f"{'ok' if ok else 'MISMATCH'}"
         )
 
@@ -154,7 +160,7 @@ def quick_smoke() -> int:
     failures += 0 if ok else 1
     us = dt / max(engine.n_decode_steps, 1) * 1e6
     print(
-        f"quick_serve_engine_{disp['logits_allreduce']['engine']},"
+        f"quick_serve_engine_{disp['logits_allreduce']['engine']},{where},"
         f"{us:.3f},{'ok' if ok else 'MISMATCH'}"
     )
     return failures

@@ -43,6 +43,8 @@ for p in (str(_ROOT), str(_ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+# virtual devices exist only on the CPU backend: never time a chip here
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", "")
@@ -220,7 +222,7 @@ def run_load_point(
     gaps = np.asarray(gaps)
     return {
         "offered_qps": float(offered_qps),
-        "tokens_per_s": float(total_tokens / makespan),
+        "sim_tokens_per_s": float(total_tokens / makespan),
         "p50_token_latency_s": float(np.percentile(gaps, 50)),
         "p99_token_latency_s": float(np.percentile(gaps, 99)),
         "completed": len(done),
@@ -296,6 +298,14 @@ def main() -> int:
         if dispatch_executed is None:
             dispatch_executed = disp
     fit = fit_self_check(fit_rows)
+    import jax
+
+    dev = jax.devices()[0]
+    device = {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "count": jax.device_count(),
+    }
 
     # 2. QPS sweep through the real Scheduler/Router (simulated clock)
     tau = per_step["8"]
@@ -333,7 +343,7 @@ def main() -> int:
     # -- checks (the CI gate) ----------------------------------------------
     checks = {}
     # tokens/s monotone non-decreasing in offered QPS up to the peak
-    tput = [pt["tokens_per_s"] for pt in curve]
+    tput = [pt["sim_tokens_per_s"] for pt in curve]
     peak = int(np.argmax(tput))
     checks["monotone_below_saturation"] = bool(
         all(tput[i + 1] >= tput[i] * (1 - 1e-9) for i in range(peak))
@@ -353,10 +363,11 @@ def main() -> int:
     report = {
         "bench": "serve_load",
         "quick": bool(args.quick),
+        "device": device,
         "measured": {
             "grid": "2x4",
             "fit_rows": [[int(s), float(t), int(k)] for s, t, k in fit_rows],
-            "per_step_s": per_step,
+            "cpu_per_step_s": per_step,
             "machine_params_fit": fit,
         },
         "dispatch": {"executed_2x4": dispatch_executed, "grids": grids},
@@ -382,7 +393,8 @@ def main() -> int:
     for name, ok in checks.items():
         print(f"check {name}: {'ok' if ok else 'FAIL'}")
     print(
-        f"qps curve: {[round(pt['tokens_per_s'], 1) for pt in curve]} tok/s "
+        f"simulated qps curve on {device['platform']} step times: "
+        f"{[round(pt['sim_tokens_per_s'], 1) for pt in curve]} tok/s "
         f"at {[round(pt['offered_qps'], 2) for pt in curve]} qps"
     )
     return failures

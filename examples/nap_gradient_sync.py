@@ -17,6 +17,8 @@ Run:  PYTHONPATH=src python examples/nap_gradient_sync.py
 
 import os
 
+# virtual devices exist only on the CPU backend: never run on a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 
 import jax

@@ -10,6 +10,8 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 
 import os
 
+# virtual devices exist only on the CPU backend: never run on a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 
 from functools import partial
@@ -19,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import collectives
 from repro.launch.mesh import make_mesh
 
@@ -30,7 +31,7 @@ def main():
 
     for algo in ["rd", "smp", "nap"]:
         fn = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 partial(
                     collectives.ALGORITHMS[algo],
                     inter_axes="pod",

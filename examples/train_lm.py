@@ -25,7 +25,9 @@ import sys
 import time
 
 if "--compressed-smoke" in sys.argv:
-    # must be set before jax initialises (import side effect below)
+    # must be set before jax initialises (import side effect below);
+    # virtual devices exist only on the CPU backend
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         "--xla_force_host_platform_device_count=8 "
         + os.environ.get("XLA_FLAGS", "")

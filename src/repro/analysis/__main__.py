@@ -52,6 +52,7 @@ import sys
 # before anything imports jax, which is why this module (and the whole
 # analysis package) keeps jax out of module scope
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual devices: CPU backend only
 
 
 def run_schedule_sweep() -> dict:
@@ -103,7 +104,6 @@ def run_hlo_lint() -> dict:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.core import comm, grad_sync
     from repro.launch.mesh import make_mesh
 
@@ -129,7 +129,7 @@ def run_hlo_lint() -> dict:
             return jnp.concatenate(out)
 
         args = [jnp.zeros(s, jnp.float32) for s in shapes]
-        g = compat.shard_map(
+        g = jax.shard_map(
             f, mesh=mesh,
             in_specs=tuple(P() for _ in args),
             out_specs=P(),

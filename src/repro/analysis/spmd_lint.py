@@ -105,6 +105,9 @@ _SCALE_SEEDS = frozenset(
 #: pallas transport kernel name prefixes (repro.kernels.transport)
 _TRANSPORT_PREFIXES = ("quantize_pack", "unpack_dequantize")
 _DONATE_RE = re.compile(r"__donate(\d+)")
+#: shape/type-only ops a kernel wrapper may put between the caller's
+#: buffer and the kernel operand: donating the view donates the source
+_VIEW_PRIMS = frozenset({"reshape", "squeeze", "expand_dims", "pvary", "pcast"})
 _BITS_RE = re.compile(r"^(?:quantize_pack|unpack_dequantize)_(\d+)b")
 
 _REL_TOL = 1e-6  # byte-accounting comparison tolerance (relative)
@@ -718,12 +721,21 @@ class _Analyzer:
         return [out] * len(eqn.outvars)
 
     def _check_dead_after(self, jaxpr, call_eqn, var, name):
+        # the donated operand and every buffer it is a view of
+        chain = [var]
+        for eqn in reversed(jaxpr.eqns[: jaxpr.eqns.index(call_eqn)]):
+            if (
+                eqn.primitive.name in _VIEW_PRIMS
+                and any(o is chain[-1] for o in eqn.outvars)
+                and not hasattr(eqn.invars[0], "val")
+            ):
+                chain.append(eqn.invars[0])
         seen = False
         for eqn in jaxpr.eqns:
             if eqn is call_eqn:
                 seen = True
                 continue
-            if seen and any(a is var for a in eqn.invars):
+            if seen and any(a is v for a in eqn.invars for v in chain):
                 self._flag(
                     "alias-donation",
                     f"{name!r} declares operand donation but the donated "
@@ -731,7 +743,7 @@ class _Analyzer:
                     "the call (alias hazard)",
                 )
                 return
-        if any(a is var for a in jaxpr.outvars):
+        if any(a is v for a in jaxpr.outvars for v in chain):
             self._flag(
                 "alias-donation",
                 f"{name!r} declares operand donation but the donated "

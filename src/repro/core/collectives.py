@@ -54,7 +54,6 @@ import numpy as np
 from jax import lax
 
 from . import napalg
-from .. import compat
 
 __all__ = [
     "nap_allreduce",
@@ -150,11 +149,11 @@ def _chip_index(inter_axes: tuple[str, ...], intra_axes: tuple[str, ...]):
     """SMP-style flat chip id: node-major, local-rank-minor."""
     node = 0
     for ax in inter_axes:
-        node = node * compat.axis_size(ax) + lax.axis_index(ax)
+        node = node * lax.axis_size(ax) + lax.axis_index(ax)
     rank = 0
     for ax in intra_axes:
-        rank = rank * compat.axis_size(ax) + lax.axis_index(ax)
-    ppn = int(np.prod([compat.axis_size(ax) for ax in intra_axes]))
+        rank = rank * lax.axis_size(ax) + lax.axis_index(ax)
+    ppn = int(np.prod([lax.axis_size(ax) for ax in intra_axes]))
     return node * ppn + rank
 
 
@@ -194,8 +193,8 @@ def nap_allreduce(
     inter, intra = _as_tuple(inter_axes), _as_tuple(intra_axes)
     fold, named_reduce, _ = _OPS[op]
     fold = _f32_fold(fold, op, x.dtype)
-    n = int(np.prod([compat.axis_size(ax) for ax in inter]))
-    ppn = int(np.prod([compat.axis_size(ax) for ax in intra]))
+    n = int(np.prod([lax.axis_size(ax) for ax in inter]))
+    ppn = int(np.prod([lax.axis_size(ax) for ax in intra]))
     sched = napalg.build_nap_schedule(n, ppn)
     joint = inter + intra
 
@@ -266,8 +265,8 @@ def rd_allreduce(
     """
     inter, intra = _as_tuple(inter_axes), _as_tuple(intra_axes)
     joint = inter + intra
-    n = int(np.prod([compat.axis_size(ax) for ax in inter]))
-    ppn = int(np.prod([compat.axis_size(ax) for ax in intra])) if intra else 1
+    n = int(np.prod([lax.axis_size(ax) for ax in inter]))
+    ppn = int(np.prod([lax.axis_size(ax) for ax in intra])) if intra else 1
     sched = napalg.build_rd_schedule(n, ppn)
     return _run_p2p_schedule(x, sched, joint, inter, intra, op)
 
@@ -288,8 +287,8 @@ def smp_allreduce(
     """
     inter, intra = _as_tuple(inter_axes), _as_tuple(intra_axes)
     joint = inter + intra
-    n = int(np.prod([compat.axis_size(ax) for ax in inter]))
-    ppn = int(np.prod([compat.axis_size(ax) for ax in intra]))
+    n = int(np.prod([lax.axis_size(ax) for ax in inter]))
+    ppn = int(np.prod([lax.axis_size(ax) for ax in intra]))
     sched = napalg.build_smp_schedule(n, ppn)
     return _run_p2p_schedule(x, sched, joint, inter, intra, op)
 
@@ -311,7 +310,7 @@ def ring_allreduce(
     fold, _, _ = _OPS[op]
     fold = _f32_fold(fold, op, x.dtype)
     ax = _as_tuple(axes)
-    p = int(np.prod([compat.axis_size(a) for a in ax]))
+    p = int(np.prod([lax.axis_size(a) for a in ax]))
     if p == 1:
         return x
     orig_shape, orig_dtype = x.shape, x.dtype
@@ -326,7 +325,7 @@ def ring_allreduce(
     chunks = flat.reshape(p, -1)
     idx = 0
     for a in ax:
-        idx = idx * compat.axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     fwd = [(i, (i + 1) % p) for i in range(p)]
 
     # reduce-scatter: after p-1 shifts, chip i owns the full sum of chunk
@@ -383,7 +382,7 @@ def rabenseifner_allreduce(
             f"rabenseifner path supports {sorted(_MLA_OPS)}, got {op!r}"
         )
     ax = _as_tuple(axes)
-    p = int(np.prod([compat.axis_size(a) for a in ax]))
+    p = int(np.prod([lax.axis_size(a) for a in ax]))
     if p == 1:
         return x
     orig_shape, orig_dtype = x.shape, x.dtype
@@ -395,7 +394,7 @@ def rabenseifner_allreduce(
         )
     tiles = flat.reshape(p, -1)
     if op == "sum" and not _needs_f32_accum(flat.dtype):
-        shard = lax.psum_scatter(tiles, ax, scatter_dimension=0, tiled=False)
+        shard = lax.psum_scatter(flat, ax, scatter_dimension=0, tiled=True)
     else:
         # reduce-scatter(max/min): every chip scatters tile j to chip j,
         # receives all chips' copies of its own tile, folds locally.
@@ -411,7 +410,7 @@ def rabenseifner_allreduce(
             ).astype(flat.dtype)
         else:
             shard = _AXIS_REDUCERS[op](gathered[0], axis=0)
-    out = lax.all_gather(shard, ax, axis=0, tiled=False).reshape(-1)
+    out = lax.all_gather(shard, ax, axis=0, tiled=True)
     if pad:
         out = out[: out.size - pad]
     return out.reshape(orig_shape).astype(orig_dtype)
@@ -444,7 +443,9 @@ def _mla_one_chunk(
     tiles = flat.reshape(ppn, -1)
     if op == "sum":
         # phase 1: stripe the pod partial across local ranks
-        stripe = lax.psum_scatter(tiles, intra, scatter_dimension=0, tiled=False)
+        stripe = lax.psum_scatter(
+            flat, intra, scatter_dimension=0, tiled=True
+        )
     else:
         gathered = lax.all_to_all(
             tiles[:, None, :], intra, split_axis=0, concat_axis=1, tiled=False
@@ -454,7 +455,7 @@ def _mla_one_chunk(
     if n > 1:
         stripe = rabenseifner_allreduce(stripe, axes=inter, op=op)
     # phase 3: rebuild the full payload inside the pod
-    out = lax.all_gather(stripe, intra, axis=0, tiled=False).reshape(-1)
+    out = lax.all_gather(stripe, intra, axis=0, tiled=True)
     if pad:
         out = out[: out.size - pad]
     return out
@@ -502,8 +503,8 @@ def mla_allreduce(
             f"mla path supports {sorted(_MLA_OPS)}, got {op!r}"
         )
     inter, intra = _as_tuple(inter_axes), _as_tuple(intra_axes)
-    ppn = int(np.prod([compat.axis_size(ax) for ax in intra]))
-    n = int(np.prod([compat.axis_size(ax) for ax in inter]))
+    ppn = int(np.prod([lax.axis_size(ax) for ax in intra]))
+    n = int(np.prod([lax.axis_size(ax) for ax in inter]))
     if ppn == 1:
         return rabenseifner_allreduce(x, axes=inter, op=op)
     orig_shape, orig_dtype = x.shape, x.dtype
@@ -548,8 +549,8 @@ def mla_pipelined_allreduce(
         from . import perf_model as pm
 
         inter, intra = _as_tuple(inter_axes), _as_tuple(intra_axes)
-        n = int(np.prod([compat.axis_size(ax) for ax in inter]))
-        ppn = int(np.prod([compat.axis_size(ax) for ax in intra]))
+        n = int(np.prod([lax.axis_size(ax) for ax in inter]))
+        ppn = int(np.prod([lax.axis_size(ax) for ax in intra]))
         nbytes = float(int(np.prod(x.shape)) * x.dtype.itemsize)
         pipeline_chunks = pm.optimal_pipeline_chunks(
             nbytes, n, ppn, params or pm.TPU_V5E_POD
@@ -590,7 +591,7 @@ def _level_reduce_scatter(
     tiles = flat.reshape(k, -1)
     wide = f32_accum and op == "sum" and _needs_f32_accum(flat.dtype)
     if op == "sum" and not wide:
-        return lax.psum_scatter(tiles, axes, scatter_dimension=0, tiled=False)
+        return lax.psum_scatter(flat, axes, scatter_dimension=0, tiled=True)
     gathered = lax.all_to_all(
         tiles[:, None, :], axes, split_axis=0, concat_axis=1, tiled=False
     )
@@ -625,8 +626,8 @@ def mla_reduce_scatter(
             f"mla_reduce_scatter supports {sorted(_MLA_OPS)}, got {op!r}"
         )
     inter, intra = _as_tuple(inter_axes), _as_tuple(intra_axes)
-    ppn = int(np.prod([compat.axis_size(ax) for ax in intra])) if intra else 1
-    n = int(np.prod([compat.axis_size(ax) for ax in inter])) if inter else 1
+    ppn = int(np.prod([lax.axis_size(ax) for ax in intra])) if intra else 1
+    n = int(np.prod([lax.axis_size(ax) for ax in inter])) if inter else 1
     flat = x.reshape(-1)
     stripe = _level_reduce_scatter(flat, intra, ppn, op)
     return _level_reduce_scatter(stripe, inter, n, op, f32_accum=True)
@@ -648,19 +649,19 @@ def mla_allgather(
     uniform-shape padding (default: assume no padding was required).
     """
     inter, intra = _as_tuple(inter_axes), _as_tuple(intra_axes)
-    ppn = int(np.prod([compat.axis_size(ax) for ax in intra])) if intra else 1
-    n = int(np.prod([compat.axis_size(ax) for ax in inter])) if inter else 1
+    ppn = int(np.prod([lax.axis_size(ax) for ax in intra])) if intra else 1
+    n = int(np.prod([lax.axis_size(ax) for ax in inter])) if inter else 1
     shard = x.reshape(-1)
     if elems is None:
         elems = shard.size * n * ppn
     stripe_len = -(-int(elems) // ppn)  # ceil: the intra-RS stripe size
     if n > 1:
-        stripe = lax.all_gather(shard, inter, axis=0, tiled=False).reshape(-1)
+        stripe = lax.all_gather(shard, inter, axis=0, tiled=True)
         stripe = stripe[:stripe_len]
     else:
         stripe = shard[:stripe_len]
     if ppn > 1:
-        full = lax.all_gather(stripe, intra, axis=0, tiled=False).reshape(-1)
+        full = lax.all_gather(stripe, intra, axis=0, tiled=True)
     else:
         full = stripe
     return full[: int(elems)]
@@ -678,7 +679,7 @@ def flat_reduce_scatter(
             f"flat_reduce_scatter supports {sorted(_MLA_OPS)}, got {op!r}"
         )
     ax = _as_tuple(axes)
-    p = int(np.prod([compat.axis_size(a) for a in ax])) if ax else 1
+    p = int(np.prod([lax.axis_size(a) for a in ax])) if ax else 1
     return _level_reduce_scatter(x.reshape(-1), ax, p, op, f32_accum=f32_accum)
 
 
@@ -688,12 +689,12 @@ def flat_allgather(
     """Single-level allgather over the flattened ``axes`` grid — inverse
     of :func:`flat_reduce_scatter` (chip-order tile layout)."""
     ax = _as_tuple(axes)
-    p = int(np.prod([compat.axis_size(a) for a in ax])) if ax else 1
+    p = int(np.prod([lax.axis_size(a) for a in ax])) if ax else 1
     shard = x.reshape(-1)
     if p <= 1:
         out = shard
     else:
-        out = lax.all_gather(shard, ax, axis=0, tiled=False).reshape(-1)
+        out = lax.all_gather(shard, ax, axis=0, tiled=True)
     if elems is None:
         elems = shard.size * p
     return out[: int(elems)]

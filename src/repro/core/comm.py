@@ -24,14 +24,13 @@ first-class, frozen, hashable object here instead of loose
 
 Quickstart — mesh to collective in a few lines::
 
-    from repro import compat
     from repro.core import comm
     from repro.launch.mesh import make_production_mesh
 
     mesh = make_production_mesh(multi_pod=True)   # 2 pods x 16 x 16
     topo = comm.Topology.from_mesh(mesh)          # n=2, ppn=256, params
     ctx = comm.CommContext(topo)                  # default auto policy
-    sync = compat.shard_map(
+    sync = jax.shard_map(
         lambda g: ctx.allreduce(g), mesh=mesh,
         in_specs=P(("pod", "data")), out_specs=P(("pod", "data")),
     )                                             # model-driven dispatch
@@ -51,10 +50,10 @@ import types
 import warnings
 from typing import Callable, NamedTuple
 
+import jax
 import numpy as np
 
 from . import collectives, napalg, perf_model as pm
-from .. import compat
 
 __all__ = [
     "Topology",
@@ -181,9 +180,9 @@ class Topology:
         (axis sizes come from ``jax.lax``/shard_map)."""
         inter = _axes_tuple(inter_axes)
         intra = _axes_tuple(intra_axes)
-        n = int(np.prod([compat.axis_size(a) for a in inter])) if inter else 1
+        n = int(np.prod([jax.lax.axis_size(a) for a in inter])) if inter else 1
         ppn = (
-            int(np.prod([compat.axis_size(a) for a in intra])) if intra else 1
+            int(np.prod([jax.lax.axis_size(a) for a in intra])) if intra else 1
         )
         return cls(
             n, ppn, inter_axes=inter, intra_axes=intra,

@@ -23,15 +23,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import napalg
-from .. import compat
 from .collectives import AxisNames, _as_tuple, _chip_index, _mask_lookup
 
 __all__ = ["nap_allgather", "nap_reduce_scatter", "nap_allreduce_large", "supported"]
 
 
 def _sizes(inter, intra):
-    n = int(np.prod([compat.axis_size(a) for a in inter]))
-    ppn = int(np.prod([compat.axis_size(a) for a in intra]))
+    n = int(np.prod([lax.axis_size(a) for a in inter]))
+    ppn = int(np.prod([lax.axis_size(a) for a in intra]))
     return n, ppn
 
 

@@ -66,7 +66,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from . import bucketing, collectives, comm
-from .. import compat
 from ..kernels import transport
 
 __all__ = [
@@ -208,7 +207,7 @@ def _wire_split(topo: comm.Topology):
 def _flat_index(axes) -> jax.Array:
     idx = jnp.zeros((), jnp.int32)
     for ax in axes:
-        idx = idx * compat.axis_size(ax) + lax.axis_index(ax)
+        idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
     return idx
 
 
@@ -295,30 +294,38 @@ def _compressed_fused_allreduce(
 
     sizes = tuple(int(p.size) for p in parts)
 
+    # rows handed to the kernels are whole transport blocks: the kernels
+    # pad every row to one anyway, and padding a ragged 2-D row makes
+    # XLA:TPU relayout it (and compile for seconds per million elements)
+    unit = transport.DEFAULT_BLOCK
     if group <= 1:
         # single chip: no wire — but keep the quantize round trip so the
         # compression semantics (and EF residuals) match any grid size
         scales = _wire_scale(flat, offsets, sizes, 0, (), qmax)
+        Ep = -(-E // unit) * unit
+        padded = flat
+        if Ep != E:
+            padded = jnp.concatenate([flat, jnp.zeros((Ep - E,), jnp.float32)])
         w = transport.quantize_pack(
-            flat.reshape(1, E), scales, offsets=offsets, bits=bits,
+            padded.reshape(1, Ep), scales, offsets=offsets, bits=bits,
             donate_input=not with_err,
         )
         full = transport.unpack_dequantize(
-            w, scales, offsets=offsets, bits=bits, cols=E,
+            w, scales, offsets=offsets, bits=bits, cols=Ep,
             donate_input=True,
-        ).reshape(-1)
+        ).reshape(-1)[:E]
         return split(full), scales, (flat - full if with_err else None)
 
     pre_axes, wire_axes, pre, g = _wire_split(ctx.topology)
     # ---- level 1: exact f32 pre-combine, striping the payload ----------
     if pre > 1:
-        S = -(-E // pre)
+        S = -(-E // (pre * unit)) * unit
         if pre * S != E:
             flat = jnp.concatenate(
                 [flat, jnp.zeros((pre * S - E,), jnp.float32)]
             )
         stripe = lax.psum_scatter(
-            flat.reshape(pre, S), pre_axes, scatter_dimension=0, tiled=False
+            flat, pre_axes, scatter_dimension=0, tiled=True
         )
         base_stripe = _flat_index(pre_axes) * S
     else:
@@ -326,7 +333,7 @@ def _compressed_fused_allreduce(
         stripe = flat
         base_stripe = jnp.zeros((), jnp.int32)
     # ---- one-pass quantize-pack of the stripe into g wire blocks -------
-    B = -(-S // g)
+    B = -(-S // (g * unit)) * unit
     if g * B != S:
         stripe = jnp.concatenate(
             [stripe, jnp.zeros((g * B - S,), jnp.float32)]
@@ -342,7 +349,7 @@ def _compressed_fused_allreduce(
     # ---- RS half: packed all_to_all; every row lands on the same block
     # window (base + t*B, row_stride=0), unpack + exact f32 fold --------
     recv = lax.all_to_all(
-        w[:, None, :], wire_axes, split_axis=0, concat_axis=1, tiled=False
+        w[:, None], wire_axes, split_axis=0, concat_axis=1, tiled=False
     )[0]
     block_base = base_stripe + _flat_index(wire_axes) * B
     blk = jnp.sum(
@@ -365,9 +372,7 @@ def _compressed_fused_allreduce(
     ).reshape(-1)[:S]
     # ---- level 1 inverse: rebuild the flat sum inside the node ---------
     if pre > 1:
-        full = lax.all_gather(
-            stripe_sum, pre_axes, axis=0, tiled=False
-        ).reshape(-1)
+        full = lax.all_gather(stripe_sum, pre_axes, axis=0, tiled=True)
     else:
         full = stripe_sum
     err = None
@@ -667,8 +672,7 @@ def _compressed_reduce_scatter(flat, scale, ctx: comm.CommContext):
         if ppn * S != e:
             flat = jnp.concatenate([flat, jnp.zeros((ppn * S - e,), jnp.float32)])
         stripe = lax.psum_scatter(
-            flat.reshape(ppn, S), topo.intra_axes,
-            scatter_dimension=0, tiled=False,
+            flat, topo.intra_axes, scatter_dimension=0, tiled=True
         )
         base = _flat_index(topo.intra_axes) * S
         s1 = scales * float(ppn)
@@ -686,7 +690,7 @@ def _compressed_reduce_scatter(flat, scale, ctx: comm.CommContext):
         base=base, row_stride=B, donate_input=True,
     )
     recv = lax.all_to_all(
-        w[:, None, :], topo.inter_axes, split_axis=0, concat_axis=1,
+        w[:, None], topo.inter_axes, split_axis=0, concat_axis=1,
         tiled=False,
     )[0]
     block_base = base + _flat_index(topo.inter_axes) * B
@@ -797,6 +801,9 @@ def make_grad_sync(
             grads, cfg=cfg, inter_axes=inter, intra_axes=intra
         )
 
-    return compat.shard_map(
-        _local, mesh=mesh, in_specs=(grad_specs,), out_specs=grad_specs
+    # the Pallas interpreter (CPU) cannot lower under vma checks; the
+    # compiled TPU kernels type their outputs either way
+    return jax.shard_map(
+        _local, mesh=mesh, in_specs=(grad_specs,), out_specs=grad_specs,
+        check_vma=False,
     )

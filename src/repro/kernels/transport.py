@@ -2,8 +2,10 @@
 
 The compressed grad-sync path used to quantize, cast, concat and stripe
 as separate XLA ops over the gradient before a single byte moved.  These
-kernels collapse that chain into **one pass per transport hop**: a grid
-cell reads a (1, block) tile of the fused f32 bucket, looks up the tile's
+kernels collapse that chain into **one pass per transport hop**: each
+row is viewed as ``(2*blocks, block/2)`` half-blocks and a grid cell
+reads a run of consecutive blocks of one row of the fused f32 bucket
+(dense in HBM and (8, 128)-tiled at any row count), looks up their
 per-leaf scale from its *global flat index* (the leaf offsets of the
 bucket — the same offsets :func:`repro.core.napalg.mla_stripe_geometry`
 charges for stripe bytes — baked in as static index maps), rounds/clips
@@ -22,8 +24,15 @@ kernel (``interpret=True`` on CPU so tier-1 validates everywhere) and
 ``impl="xla"`` routes to the pure-jnp oracle in :mod:`repro.kernels.ref`,
 which is bit-identical on the wire bytes.
 
-Index plumbing: a wire array is (R, C) — R rows that are *blocks of a
-stripe* (or per-rank copies of one block).  Element (i, c) of the padded
+Index plumbing: the input is (R, C) — R rows that are *blocks of a
+stripe* (or per-rank copies of one block) — and the wire array is
+(R, rows, block/2): row ``i``'s ``nb = ceil(C/block)`` blocks as two
+half-block rows each (8-bit) or one packed row (int4; its low nibbles
+are the block's first half, read with a stride-2 row slice).  With
+``block == 256`` every wire row is 128 lanes, so flattening a wire
+array is free on the TPU; small-row or 256-wide 8-bit arrays would
+need relayouts that take XLA seconds per million elements to compile.
+Element (i, c) of the padded
 input corresponds to global flat-bucket index ``base + i*row_stride + c``
 (``base`` is traced — it depends on ``lax.axis_index`` — and
 ``row_stride`` is static: the padded block length for sequential blocks,
@@ -52,6 +61,8 @@ __all__ = [
 ]
 
 DEFAULT_BLOCK = 256
+# blocks per grid cell: a multiple of 32 so 8-bit wire tiles are whole
+TILE_BLOCKS = 256
 
 
 def _on_cpu() -> bool:
@@ -89,13 +100,18 @@ def _pad_cols(x: jax.Array, block: int) -> jax.Array:
     return x
 
 
-def _tile_index(base, i, j, *, block: int, row_stride: int):
-    """Global flat-bucket index of every element in grid cell (i, j)."""
+def _tile_index(base, start, *, rows: int, step: int, half: int,
+                row_stride: int):
+    """Global flat-bucket index of a (rows, half) tile of grid cell
+    (i, j): tile row ``r`` lane ``c`` sits at ``start + r*step + c`` of
+    input row ``i``."""
+    shape = (rows, half)
     return (
         base
-        + i * row_stride
-        + j * block
-        + lax.broadcasted_iota(jnp.int32, (1, block), 1)
+        + pl.program_id(0) * row_stride
+        + start
+        + lax.broadcasted_iota(jnp.int32, shape, 0) * step
+        + lax.broadcasted_iota(jnp.int32, shape, 1)
     )
 
 
@@ -109,50 +125,123 @@ def _tile_scale(scales_ref, idx, *, offsets):
     return scale
 
 
+def _halves(tile: int, block: int):
+    """Grid cell j's ``tile`` blocks as (lo, hi) half-rows of the
+    (2*nb, half) view: strided row slices and their index starts."""
+    j = pl.program_id(1)
+    return (
+        (pl.ds(0, tile, stride=2), j * tile * block),
+        (pl.ds(1, tile, stride=2), j * tile * block + block // 2),
+    )
+
+
 def _quant_kernel(
     base_ref, scales_ref, x_ref, o_ref, *, offsets, bits, block, row_stride
 ):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    idx = _tile_index(
-        base_ref[0, 0], i, j, block=block, row_stride=row_stride
-    )
-    scale = _tile_scale(scales_ref, idx, offsets=offsets)
+    half = block // 2
     qmax = float(2 ** (bits - 1) - 1)
-    q = jnp.clip(
-        jnp.round(x_ref[...].astype(jnp.float32) / scale), -qmax, qmax
-    ).astype(jnp.int32)
+
+    def quant(rows_sl, rows, start, step):
+        idx = _tile_index(
+            base_ref[0, 0], start, rows=rows, step=step, half=half,
+            row_stride=row_stride,
+        )
+        scale = _tile_scale(scales_ref, idx, offsets=offsets)
+        return jnp.clip(
+            jnp.round(x_ref[rows_sl] / scale), -qmax, qmax
+        ).astype(jnp.int32)
+
     if bits == 4:
-        half = block // 2
-        lo, hi = q[:, :half], q[:, half:]
+        tile = o_ref.shape[0]
+        (lo_sl, lo_start), (hi_sl, hi_start) = _halves(tile, block)
+        lo = quant(lo_sl, tile, lo_start, block)
+        hi = quant(hi_sl, tile, hi_start, block)
         o_ref[...] = ((lo & 0xF) | ((hi & 0xF) << 4)).astype(jnp.uint8)
     else:
+        rows = x_ref.shape[0]
+        q = quant(slice(None), rows, pl.program_id(1) * rows * half, half)
         o_ref[...] = q.astype(jnp.int8)
 
 
 def _dequant_kernel(
     base_ref, scales_ref, w_ref, o_ref, *, offsets, bits, block, row_stride
 ):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    idx = _tile_index(
-        base_ref[0, 0], i, j, block=block, row_stride=row_stride
-    )
-    scale = _tile_scale(scales_ref, idx, offsets=offsets)
+    half = block // 2
+
+    def scale(rows, start, step):
+        idx = _tile_index(
+            base_ref[0, 0], start, rows=rows, step=step, half=half,
+            row_stride=row_stride,
+        )
+        return _tile_scale(scales_ref, idx, offsets=offsets)
+
     if bits == 4:
+        tile = w_ref.shape[0]
+        (lo_sl, lo_start), (hi_sl, hi_start) = _halves(tile, block)
         b = w_ref[...].astype(jnp.int32)
         lo = b & 0xF
         hi = (b >> 4) & 0xF
         lo = jnp.where(lo > 7, lo - 16, lo)
         hi = jnp.where(hi > 7, hi - 16, hi)
-        q = jnp.concatenate([lo, hi], axis=1)
+        o_ref[lo_sl] = lo.astype(jnp.float32) * scale(tile, lo_start, block)
+        o_ref[hi_sl] = hi.astype(jnp.float32) * scale(tile, hi_start, block)
     else:
+        rows = w_ref.shape[0]
         q = w_ref[...].astype(jnp.int32)
-    o_ref[...] = q.astype(jnp.float32) * scale
+        o_ref[...] = q.astype(jnp.float32) * scale(
+            rows, pl.program_id(1) * rows * half, half
+        )
 
 
 def _scalar_2d(v) -> jax.Array:
     return jnp.asarray(v, jnp.int32).reshape(1, 1)
+
+
+def _blocked_call(kern, name, interpret, base, scales, x3, *, nb: int,
+                  in_rows: int, out_rows: int, out_dtype):
+    """Run ``kern`` over ``x3`` (R, nb*in_rows, half): one grid cell per
+    row and run of ``TILE_BLOCKS`` blocks, each block ``in_rows`` rows
+    in and ``out_rows`` rows out (the last run may be partial; its
+    out-of-range rows are never written).  Returns
+    (R, nb*out_rows, half) of ``out_dtype``."""
+    R, _, half = x3.shape
+    tile = min(nb, TILE_BLOCKS)
+    L = scales.shape[0]
+    (base2, scales2, x3), vma = _vary_together(
+        _scalar_2d(base), scales.reshape(1, L), x3
+    )
+    return pl.pallas_call(
+        kern,
+        name=name,
+        grid=(R, pl.cdiv(nb, tile)),
+        in_specs=[
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, L), lambda i, j: (0, 0)),
+            pl.BlockSpec(
+                (pl.Squeezed(), tile * in_rows, half), lambda i, j: (i, j, 0)
+            ),
+        ],
+        out_specs=pl.BlockSpec(
+            (pl.Squeezed(), tile * out_rows, half), lambda i, j: (i, j, 0)
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (R, nb * out_rows, half), out_dtype, vma=vma
+        ),
+        interpret=_on_cpu() if interpret is None else interpret,
+    )(base2, scales2, x3)
+
+
+def _vary_together(*operands):
+    """Cast every operand to vary over the union of the mesh axes any of
+    them varies over (a no-op outside ``shard_map``), so the call traces
+    inside a ``check_vma=True`` shard_map.  Returns the operands and that
+    union, which is also the output's ``vma``."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    out = []
+    for a in operands:
+        missing = tuple(sorted(vma - jax.typeof(a).vma))
+        out.append(lax.pcast(a, missing, to="varying") if missing else a)
+    return out, vma
 
 
 def quantize_pack(
@@ -170,9 +259,10 @@ def quantize_pack(
 ) -> jax.Array:
     """Quantize-and-pack ``x`` (R, C) f32 into wire bytes in one pass.
 
-    Returns (R, ceil(C/block)*block * wire_itemsize(bits)) wire bytes
-    (columns zero-padded up to a ``block`` multiple; the pad quantizes
-    to 0 and is sliced off by :func:`unpack_dequantize`).  ``scales`` is
+    Returns (R, rows, block/2) wire bytes, ``rows`` being ``2*nb`` at 8
+    bits and ``nb`` packed at 4, ``nb = ceil(C/block)`` (columns
+    zero-padded up to a ``block`` multiple; the pad quantizes to 0 and
+    is sliced off by :func:`unpack_dequantize`).  ``scales`` is
     the (L,) per-leaf scale vector, ``offsets`` the static leaf start
     indices, ``base``/``row_stride`` the global-index plumbing (module
     docstring).
@@ -191,32 +281,22 @@ def quantize_pack(
     _check_args(bits, block, scales.shape[0], offsets)
     xp = _pad_cols(jnp.asarray(x, jnp.float32), block)
     R, Cp = xp.shape
+    nb, half = Cp // block, block // 2
     if impl == "xla":
         return ref.quantize_pack_ref(
             xp, scales, offsets=offsets, bits=bits, base=base,
             row_stride=row_stride, block=block,
-        )
-    L = scales.shape[0]
-    wblock = block // 2 if bits == 4 else block
-    out_cols = (Cp // block) * wblock
+        ).reshape(R, -1, half)
     kern = functools.partial(
         _quant_kernel,
         offsets=offsets, bits=bits, block=block, row_stride=int(row_stride),
     )
     name = f"quantize_pack_{bits}b" + ("__donate2" if donate_input else "")
-    return pl.pallas_call(
-        kern,
-        name=name,
-        grid=(R, Cp // block),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, L), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, wblock), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, out_cols), wire_dtype(bits)),
-        interpret=_on_cpu() if interpret is None else interpret,
-    )(_scalar_2d(base), scales.reshape(1, L), xp)
+    return _blocked_call(
+        kern, name, interpret, base, scales, xp.reshape(R, 2 * nb, half),
+        nb=nb, in_rows=2, out_rows=1 if bits == 4 else 2,
+        out_dtype=wire_dtype(bits),
+    )
 
 
 def unpack_dequantize(
@@ -233,8 +313,9 @@ def unpack_dequantize(
     interpret: bool | None = None,
     donate_input: bool = False,
 ) -> jax.Array:
-    """Inverse of :func:`quantize_pack`: wire bytes (R, Cw) back to
-    (R, cols) f32 values (``q * scale``), slicing off the block padding.
+    """Inverse of :func:`quantize_pack`: wire bytes (R, rows, block/2)
+    back to (R, cols) f32 values (``q * scale``), slicing off the block
+    padding.
 
     ``base``/``row_stride``/``scales``/``offsets`` must describe the
     global indices of the *received* rows — for all-to-all-received
@@ -248,20 +329,21 @@ def unpack_dequantize(
     offsets = tuple(int(o) for o in offsets)
     scales = jnp.asarray(scales, jnp.float32).reshape(-1)
     _check_args(bits, block, scales.shape[0], offsets)
-    R, Cw = wire.shape
-    wblock = block // 2 if bits == 4 else block
-    if Cw % wblock:
+    half = block // 2
+    per_block = 1 if bits == 4 else 2
+    if wire.ndim != 3 or wire.shape[2] != half or wire.shape[1] % per_block:
         raise ValueError(
-            f"wire width {Cw} is not a multiple of the {wblock}-byte "
-            f"wire block (bits={bits}, block={block})"
+            f"wire shape {wire.shape} is not (R, {per_block}*blocks, "
+            f"{half}): the wire block of bits={bits}, block={block}"
         )
+    R, rows, _ = wire.shape
+    nb = rows // per_block
     if impl == "xla":
         out = ref.unpack_dequantize_ref(
-            wire, scales, offsets=offsets, bits=bits, base=base,
-            row_stride=row_stride, block=block,
+            wire.reshape(R, rows * half), scales, offsets=offsets, bits=bits,
+            base=base, row_stride=row_stride, block=block,
         )
         return out[:, :cols]
-    L = scales.shape[0]
     kern = functools.partial(
         _dequant_kernel,
         offsets=offsets, bits=bits, block=block, row_stride=int(row_stride),
@@ -269,19 +351,8 @@ def unpack_dequantize(
     name = (
         f"unpack_dequantize_{bits}b" + ("__donate2" if donate_input else "")
     )
-    out = pl.pallas_call(
-        kern,
-        name=name,
-        grid=(R, Cw // wblock),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, L), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, wblock), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct(
-            (R, (Cw // wblock) * block), jnp.float32
-        ),
-        interpret=_on_cpu() if interpret is None else interpret,
-    )(_scalar_2d(base), scales.reshape(1, L), wire)
-    return out[:, :cols]
+    out = _blocked_call(
+        kern, name, interpret, base, scales, wire, nb=nb, in_rows=per_block,
+        out_rows=2, out_dtype=jnp.float32,
+    )
+    return out.reshape(R, nb * block)[:, :cols]

@@ -1,9 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual devices: CPU backend only
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay first (before any jax import) — jax locks
+The three lines above MUST stay first (before any jax import) — jax locks
 the device count at first backend init; the 512 virtual CPU devices make
 ``make_production_mesh()`` buildable on this single-CPU container.
 
@@ -29,7 +30,6 @@ from pathlib import Path  # noqa: E402
 
 import jax  # noqa: E402
 
-from .. import compat  # noqa: E402
 from ..configs import ARCHS, SHAPES, get_config  # noqa: E402
 from . import roofline as rl  # noqa: E402
 from .mesh import make_production_mesh  # noqa: E402
@@ -167,7 +167,7 @@ def run_cell(
             ),
             "alias_bytes": getattr(mem, "alias_size_in_bytes", None),
         }
-        cost = dict(compat.normalize_cost_analysis(cost)) if cost else {}
+        cost = dict(cost) if cost else {}
         record["cost"] = {
             k: float(v)
             for k, v in cost.items()

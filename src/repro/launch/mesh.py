@@ -9,9 +9,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from ..compat import mesh_axis_types_kwargs
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
     "make_mesh",
@@ -46,7 +44,7 @@ def make_mesh(shape, axes):
     return Mesh(
         np.asarray(devs[:n]).reshape(shape),
         tuple(axes),
-        **mesh_axis_types_kwargs(len(axes)),
+        axis_types=(AxisType.Auto,) * len(axes),
     )
 
 

@@ -42,10 +42,10 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..configs import get_config, reduced
 from ..core import comm
 from ..models import build_model
+from ..runtime import enable_compile_cache
 from ..serve.decode import make_decode_loop  # noqa: F401  (re-export)
 from .steps import make_policy, make_serve_step  # noqa: F401  (re-export)
 
@@ -130,7 +130,7 @@ def serve_batch(
     shard_fn = make_serve_shard(
         model, ctx, gen_len=gen_len, max_len=max_len, eos_id=eos_id
     )
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), P(joint)),
@@ -149,6 +149,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--eos-id", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import compat
 from ..configs import SHAPES, get_config
 from ..models import ShardingPolicy, build_model
 from ..optim import adamw_init, adamw_update, make_schedule
@@ -38,6 +37,7 @@ __all__ = [
     "make_policy",
     "make_train_step",
     "make_dp_train_step",
+    "init_dp_train_state",
     "make_prefill_step",
     "make_serve_step",
     "input_specs",
@@ -190,7 +190,8 @@ def make_dp_train_step(cfg, opt_cfg: OptimizerConfig, mesh, sync_cfg):
     group axis laid out over the mesh (residuals are chip-local state
     and must never be stored replicated).  Each step syncs ``g + r``
     through the quantised transport and stores back what the wire
-    dropped.
+    dropped.  :func:`init_dp_train_state` builds the whole state already
+    laid out on the mesh.
     """
     from ..core import comm, grad_sync
     from ..models import ShardingPolicy
@@ -251,13 +252,45 @@ def make_dp_train_step(cfg, opt_cfg: OptimizerConfig, mesh, sync_cfg):
     if use_ef:
         state_spec["ef"] = P(topo.axes)
     batch_spec = P(topo.axes, None)
-    return compat.shard_map(
+    return jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(state_spec, batch_spec),
         out_specs=(state_spec, P()),
         check_vma=False,
     )
+
+
+def init_dp_train_state(cfg, opt_cfg: OptimizerConfig, mesh, sync_cfg, key):
+    """The :func:`make_dp_train_step` state, created in place on ``mesh``.
+
+    Parameters and Adam moments are made replicated and the error-
+    feedback residuals (with ``sync_cfg.error_feedback``) sharded over
+    the mesh axes by one jitted init, so no leaf is first built whole on
+    one device and copied out from there.
+    """
+    from ..optim import ef_init
+    from .mesh import mesh_topology
+
+    model = build_model(cfg, ShardingPolicy())
+    topo = mesh_topology(mesh)
+    use_ef = bool(getattr(sync_cfg, "error_feedback", False))
+
+    def init(key):
+        params = model.init(key)
+        state = {
+            "params": params,
+            "opt": adamw_init(params, moment_dtype=opt_cfg.moment_dtype),
+        }
+        if use_ef:
+            state["ef"] = ef_init(params, group=topo.group)
+        return state
+
+    rep = NamedSharding(mesh, P())
+    shardings = {"params": rep, "opt": rep}
+    if use_ef:
+        shardings["ef"] = NamedSharding(mesh, P(topo.axes))
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def make_prefill_step(model, *, tail: int = 128):

@@ -34,7 +34,7 @@ from ..configs.base import OptimizerConfig, TrainConfig
 from ..data import Prefetcher, SyntheticLM
 from ..models import build_model
 from ..optim import adamw_init
-from ..runtime import ResumableLoop, StragglerMonitor
+from ..runtime import ResumableLoop, StragglerMonitor, enable_compile_cache
 from .mesh import dp_axes as mesh_dp_axes
 from .steps import make_policy, make_train_step
 
@@ -113,6 +113,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--microbatch", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO)
     cfg = get_config(args.arch)

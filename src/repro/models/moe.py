@@ -31,7 +31,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .layers import _ACTS, dense, init_dense, init_glu_mlp, glu_mlp
 
 __all__ = ["init_moe", "moe_apply"]
@@ -130,7 +129,7 @@ def _route_ep(
     the down-projection yields partial sums reduced with one activation-
     sized psum (no weight gathers; the 2D-serve optimization).
     """
-    ranks = compat.axis_size(tp_axis)
+    ranks = lax.axis_size(tp_axis)
     if fsdp_axes:
         # FSDP shards the *reduce* dim: axis 1 (D) for gate/up, axis 2 (D)
         # for down (its layout is (E, F, D)).
@@ -240,7 +239,7 @@ def moe_apply(params, x: jax.Array, *, cfg, policy):
             if policy.mode == "serve2d"
             else ()
         )
-        routed = compat.shard_map(
+        routed = jax.shard_map(
             partial(
                 _route_ep,
                 tp_axis=policy.tp_axis,

@@ -1,3 +1,9 @@
+from .compile_cache import enable_compile_cache
 from .fault import ResumableLoop, StragglerMonitor, elastic_remesh
 
-__all__ = ["ResumableLoop", "StragglerMonitor", "elastic_remesh"]
+__all__ = [
+    "ResumableLoop",
+    "StragglerMonitor",
+    "elastic_remesh",
+    "enable_compile_cache",
+]

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
 from ..core import comm
 from ..models.layers import softcap
 
@@ -54,7 +53,7 @@ def _flat_axis_index(axes: tuple[str, ...]) -> jax.Array:
     none)."""
     idx = jnp.zeros((), jnp.int32)
     for ax in axes:
-        idx = idx * compat.axis_size(ax) + lax.axis_index(ax)
+        idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
     return idx
 
 

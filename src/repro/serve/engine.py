@@ -34,9 +34,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import compat
 from ..core import comm
 from . import decode as _decode
 from .scheduler import PromptBuckets, Request, Scheduler
@@ -49,7 +48,7 @@ class ServeEngine:
 
     Args:
       model: a :class:`repro.models.Model` (needs the decode pair).
-      params: model parameters (replicated on the mesh if given).
+      params: model parameters (placed replicated on the mesh if given).
       num_slots: logical decode batch width (the scheduler's slot
         count).  With a mesh this is padded up to a multiple of the
         group size for the executed lowering; the pad slots are never
@@ -87,6 +86,8 @@ class ServeEngine:
         clock: Callable[[], float] = time.monotonic,
     ):
         self.model = model
+        if mesh is not None:
+            params = jax.device_put(params, NamedSharding(mesh, P()))
         self.params = params
         self.max_len = int(max_len)
         self.slice_len = int(slice_len)
@@ -151,16 +152,24 @@ class ServeEngine:
 
     def _init_slot_cache(self):
         """Slot-stacked cache: every leaf gets a leading slot axis over
-        an inner B=1 cache (the scalar ``index`` becomes ``(slots,)``)."""
-        b1 = self.model.init_decode(
-            self.params, 1, max_len=self.max_len, batch=self._b1_extras()
-        )
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(
-                x[None], (self.padded_slots,) + x.shape
-            ),
-            b1,
-        )
+        an inner B=1 cache (the scalar ``index`` becomes ``(slots,)``).
+        With a mesh it is created already sharded over the slot axis."""
+
+        def init(params):
+            b1 = self.model.init_decode(
+                params, 1, max_len=self.max_len, batch=self._b1_extras()
+            )
+            return jax.tree.map(
+                lambda x: jnp.broadcast_to(
+                    x[None], (self.padded_slots,) + x.shape
+                ),
+                b1,
+            )
+
+        if self.mesh is None:
+            return init(self.params)
+        slots = NamedSharding(self.mesh, P(self.ctx.topology.axes))
+        return jax.jit(init, out_shardings=slots)(self.params)
 
     # -- compiled programs ---------------------------------------------------
 
@@ -173,7 +182,7 @@ class ServeEngine:
             return jax.jit(slice_fn)
         joint = self.ctx.topology.axes
         spec = P(joint)  # pytree prefix: shard the leading slot axis
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             slice_fn,
             mesh=self.mesh,
             in_specs=(P(), spec, spec, spec),

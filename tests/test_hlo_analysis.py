@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import normalize_cost_analysis
 from repro.launch.hlo_analysis import analyze_hlo
 
 
@@ -39,9 +38,7 @@ def test_scan_multiplies_by_trip_count():
     expected = 17 * 2 * 64**3
     assert st.flops == pytest.approx(expected, rel=0.02)
     # cost_analysis undercounts by ~17x — that's why this module exists
-    cost = normalize_cost_analysis(
-        jax.jit(f).lower(jnp.ones((64, 64))).compile().cost_analysis()
-    )
+    cost = jax.jit(f).lower(jnp.ones((64, 64))).compile().cost_analysis()
     assert cost["flops"] < expected / 8
 
 
@@ -100,7 +97,6 @@ def test_collectives_inside_scan_multiply():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         import sys; sys.path.insert(0, "src")
-        from repro import compat
         from repro.launch.mesh import make_mesh
         from repro.launch.hlo_analysis import analyze_hlo
 
@@ -110,7 +106,7 @@ def test_collectives_inside_scan_multiply():
                 return jax.lax.psum(c, "d"), None
             out, _ = jax.lax.scan(body, x, None, length=6)
             return out
-        g = compat.shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None))
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None))
         txt = jax.jit(g).lower(jnp.ones((1024,))).compile().as_text()
         st = analyze_hlo(txt)
         # 6 all-reduces of 4 KiB each, wire = 2*size*(7/8)

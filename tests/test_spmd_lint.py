@@ -171,12 +171,10 @@ def test_shard_map_shadowing_fires():
     (``axes_bound_at_root=False``) is the legitimate first binding."""
     from jax.sharding import AbstractMesh
 
-    from repro import compat
-
     # AbstractMesh traces on any device count — the lint only ever sees
     # the jaxpr, never a device
-    mesh = AbstractMesh((("pod", 2), ("data", 4)))
-    inner = compat.shard_map(
+    mesh = AbstractMesh((2, 4), ("pod", "data"))
+    inner = jax.shard_map(
         lambda v: lax.psum(v, ("pod", "data")),
         mesh=mesh,
         in_specs=jax.sharding.PartitionSpec("data"),

@@ -45,6 +45,8 @@ def _payload(key, rows, cols, scale=1.0):
         (4, 256, 1024, 0),     # a2a-received copies of one block
         (3, 100, 512, 128),    # ragged -> column padding path
         (2, 777, 33, 1024),
+        # more blocks than one grid tile: a partial last tile per row
+        (2, 256 * 300 + 17, 5, 256 * 301),
     ],
 )
 def test_wire_bytes_bit_identical(bits, rows, cols, base, row_stride):
@@ -116,8 +118,8 @@ def test_int4_packs_two_elements_per_byte():
     scales = jnp.asarray([0.1], jnp.float32)
     w8 = transport.quantize_pack(x, scales, offsets=(0,), bits=8)
     w4 = transport.quantize_pack(x, scales, offsets=(0,), bits=4)
-    assert w8.dtype == jnp.int8 and w8.shape == (2, 512)
-    assert w4.dtype == jnp.uint8 and w4.shape == (2, 256)
+    assert w8.dtype == jnp.int8 and w8.shape == (2, 4, 128)
+    assert w4.dtype == jnp.uint8 and w4.shape == (2, 2, 128)
     assert transport.wire_itemsize(4) == 0.5
     assert transport.wire_itemsize(8) == 1.0
 
@@ -128,7 +130,7 @@ def test_int4_split_half_nibble_layout():
     w = transport.quantize_pack(
         vals, jnp.ones((1,)), offsets=(0,), bits=4, block=8
     )
-    got = np.asarray(w)[0]
+    got = np.asarray(w)[0, 0]
     q = np.asarray([1, 2, 3, -1, -2, 7, 0, -7])  # clip at qmax=7
     want = (q[:4] & 0xF) | ((q[4:] & 0xF) << 4)
     np.testing.assert_array_equal(got, want.astype(np.uint8))
@@ -193,7 +195,6 @@ def test_fused_bucket_is_four_pallas_calls_and_wire_dtype():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         import sys; sys.path.insert(0, "src")
-        from repro import compat
         from repro.core import comm, grad_sync
         from repro.launch.mesh import make_mesh
 
@@ -229,7 +230,7 @@ def test_fused_bucket_is_four_pallas_calls_and_wire_dtype():
             args = [jnp.zeros(s, jnp.float32) for s in shapes]
             if ef:
                 args += [jnp.zeros(s, jnp.float32) for s in shapes]
-            g = compat.shard_map(
+            g = jax.shard_map(
                 f, mesh=mesh,
                 in_specs=tuple(P() for _ in args),
                 out_specs=P() if not ef else (P(), P()),
