@@ -6,18 +6,17 @@ Default (one chip): ``minicpm-2b`` at its published widths (d_model
 ``--seed``.
 
 * **serve** — a :class:`repro.serve.ServeEngine` with all 40 layers, 8
-  slots and 256-token caches serves prompts of two padded buckets, one
+  slots and 1024-token caches serves prompts of two padded buckets, one
   request joining in flight.  Its tokens must equal a serial
-  one-at-a-time run through the same engine and the fixed-batch
-  reference :func:`repro.launch.serve.serve_batch`.  (At 512 cache
-  tokens and more the decode slice does not fit 16 GB: every step it
-  copies the whole slot-stacked cache and the stacked weights into
-  other layouts.)
+  one-at-a-time run through the same engine, and be greedy choices of
+  the plain decode step that :func:`repro.launch.serve.serve_batch`
+  runs (see ``GREEDY_MARGIN_TOL``).
 * **train** — :func:`repro.launch.steps.make_dp_train_step` on a
-  one-chip ``("pod", "data")`` mesh, depth cut to fit one chip, 3 steps,
-  once uncompressed and once with int8 transport (the Pallas transport
-  kernels, compiled: ``tpu_custom_call`` must be in the step's HLO).
-  Losses must be finite and step 0 within 0.5 of ln(vocab).
+  one-chip ``("pod", "data")`` mesh, depth cut to fit one chip, 4 steps
+  on one fixed batch, once uncompressed and once with int8 transport
+  (the Pallas transport kernels, compiled: ``tpu_custom_call`` must be
+  in the step's HLO).  Step 0 must sit within 0.5 of ln(vocab) and the
+  loss must fall once updates start.
 
 ``--chips 4`` (one v5e:2x2 host) runs only what exists across chips:
 the allreduce engines against a NumPy sum, NAP-synced training against
@@ -74,10 +73,21 @@ MESH_AXES = ("pod", "data")
 
 # bf16 greedy decode through two different programs (the engine's
 # slot-vmapped slice, serve_batch's batched step) rounds differently and
-# can flip near-tied argmaxes.  Where the tokens part, the engine's token
-# must still be a greedy choice of the plain decode step's logits to
-# within this many logit units: 16 bf16 ulps at unit scale.
+# can flip near-tied argmaxes.  Each engine token must be a greedy
+# choice of the plain decode step's logits to within this many logit
+# units: 8 bf16 ulps at 1.0 (the ulp there is 2^-7).  Random minicpm-2b
+# weights give logits of standard deviation ~1, so a token that is not
+# among the near-ties falls short of the max by ~4.  The serve phase
+# plants two faults (tokens one step late; tokens teacher-forced under
+# another request's prompt) and requires both to exceed this bound.
 GREEDY_MARGIN_TOL = 2.0**-4
+
+# train: Adam's first updates are sign-like, and with bf16 weights an
+# update below half an ulp is lost.  At lr 1e-4 every weight near the
+# 0.02 init scale moves by a whole ulp (~1.2e-4) at once and the loss
+# rose 1.5 nats in one step on the chip; at 2e-5 only the smaller
+# weights move, by about lr, which is a descent step.
+TRAIN_LR = 2e-5
 
 
 class SmokeFailure(RuntimeError):
@@ -99,21 +109,37 @@ def peak_bytes() -> int | None:
     return None if stats is None else int(stats["peak_bytes_in_use"])
 
 
-def greedy_margins(model, params, prompt, tokens, max_len: int) -> list:
+def _gaps(logits, ids):
+    """How far below the max logit each of ``ids`` falls, plus the
+    top-1 minus top-2 and top-1 minus median gaps (the logit spread)."""
+    row = logits[0, -1].astype(jnp.float32)
+    top2 = jax.lax.top_k(row, 2)[0]
+    return top2[0] - row[ids], top2[0] - top2[1], top2[0] - jnp.median(row)
+
+
+def greedy_margins(model, params, prompt, tokens, max_len: int) -> dict:
     """Teacher-force ``prompt + tokens`` through the plain decode step
-    (the one :func:`serve_batch` prefills with); for each generated
-    token, how far its logit falls below the step's max logit (0 where
-    it is the argmax)."""
+    (the one :func:`serve_batch` runs).  For each generated token, how
+    far its logit falls below the step's max logit (0 where it is the
+    argmax): ``own``; the same for the next token, i.e. tokens emitted
+    one step late (``late``, a planted fault); and the logit spread."""
     step = jax.jit(model.decode_step)
-    gap = jax.jit(lambda l, k: jnp.max(l[0, -1]) - l[0, -1, k])
+    gaps = jax.jit(_gaps)
     cache = model.init_decode(params, 1, max_len=max_len)
     seq = list(prompt) + list(tokens[:-1])
-    out = []
+    rows = []
     for t, tok in enumerate(seq):
         logits, cache = step(params, cache, jnp.asarray([[tok]], jnp.int32))
-        if t >= len(prompt) - 1:
-            out.append(float(gap(logits, tokens[t - len(prompt) + 1])))
-    return out
+        g = t - len(prompt) + 1  # index of the token this step emits
+        if g >= 0:
+            ids = jnp.asarray([tokens[g], tokens[min(g + 1, len(tokens) - 1)]])
+            rows.append(jax.device_get(gaps(logits, ids)))
+    return {
+        "own": [float(r[0][0]) for r in rows],
+        "late": [float(r[0][1]) for r in rows[:-1]],
+        "top1_top2": [float(r[1]) for r in rows],
+        "top1_median": [float(r[2]) for r in rows],
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -126,7 +152,7 @@ def serve_phase(
     *,
     seed: int,
     num_slots: int = 8,
-    max_len: int = 256,
+    max_len: int = 1024,
     gen_len: int = 32,
     prompt_lens=(40, 100, 56, 120, 72),
     buckets=(64, 128),
@@ -135,9 +161,9 @@ def serve_phase(
     """Serve ``prompt_lens`` prompts serially and then continuously
     batched (the last joins in flight) through one engine; without a
     mesh also through :func:`serve_batch`.  Raises unless continuous and
-    serial tokens are identical and, where the engine's tokens part from
-    ``serve_batch``'s, each is a greedy choice of the plain decode step
-    within ``GREEDY_MARGIN_TOL``."""
+    serial tokens are identical and, without a mesh, every engine token
+    is a greedy choice of the plain decode step within
+    ``GREEDY_MARGIN_TOL`` while both planted faults exceed it."""
     model = build_model(cfg)
     init = jax.jit(
         model.init,
@@ -212,10 +238,27 @@ def serve_phase(
             greedy_margins(model, params, p, toks, max_len)
             for p, toks in zip(prompts, serial)
         ]
-        result["max_greedy_margin"] = max(max(m) for m in margins)
-        result["greedy_margins_nonzero"] = sorted(
-            m for ms in margins for m in ms if m > 0
-        )
+        # planted fault: each request's tokens under the next prompt
+        # (cache rows crossed between slots)
+        swapped = [
+            greedy_margins(model, params, p, toks, max_len)["own"]
+            for p, toks in zip(prompts[1:] + prompts[:1], serial)
+        ]
+        own = [x for m in margins for x in m["own"]]
+        spread = {
+            k: np.percentile([x for m in margins for x in m[k]],
+                             [0, 50, 100]).tolist()
+            for k in ("top1_top2", "top1_median")
+        }
+        result["max_greedy_margin"] = max(own)
+        result["greedy_margins_nonzero"] = sorted(x for x in own if x > 0)
+        result["logit_spread_min_med_max"] = spread
+        # a fault is caught when some token of the request exceeds the
+        # bound; the weakest request is the one that counts
+        result["fault_margin"] = {
+            "late": min(max(m["late"]) for m in margins),
+            "swapped": min(max(m) for m in swapped),
+        }
     log(f"serve: {json.dumps(result)}")
     require(result["continuous_eq_serial"], "continuous != serial tokens")
     if mesh is None:
@@ -225,6 +268,12 @@ def serve_phase(
             f"margin {result['max_greedy_margin']} > {GREEDY_MARGIN_TOL} "
             f"(first mismatch with serve_batch: {result['mismatch_at']})",
         )
+        for fault, m in result["fault_margin"].items():
+            require(
+                m > GREEDY_MARGIN_TOL,
+                f"the margin check cannot see a planted fault ({fault}): "
+                f"{m} <= {GREEDY_MARGIN_TOL}",
+            )
     return result
 
 
@@ -235,13 +284,17 @@ def train_phase(
     seed: int,
     batch: int,
     seq: int,
-    steps: int = 3,
+    steps: int = 4,
     sync_cfg: comm.CommPolicy = comm.CommPolicy(),
 ) -> dict:
     """``steps`` steps of the explicit-collective DP train step on
-    ``mesh`` with ``batch`` sequences per chip.  Raises unless the losses
-    are finite and step 0 sits within 0.5 of ln(vocab)."""
-    opt_cfg = OptimizerConfig(lr=1e-4, schedule="constant", warmup_steps=1)
+    ``mesh`` with ``batch`` sequences per chip, all on one batch.  The
+    warmup starts at lr 0, so step 0 updates nothing and every later
+    step does.  Raises unless the losses are finite, step 0 sits within
+    0.5 of ln(vocab), and the loss falls at every step from step 1 on."""
+    opt_cfg = OptimizerConfig(
+        lr=TRAIN_LR, schedule="constant", warmup_steps=1
+    )
     topo = mesh_topology(mesh)
     state = init_dp_train_state(
         cfg, opt_cfg, mesh, sync_cfg, jax.random.PRNGKey(seed)
@@ -254,14 +307,14 @@ def train_phase(
     step = jax.jit(
         make_dp_train_step(cfg, opt_cfg, mesh, sync_cfg), donate_argnums=(0,)
     )
+    b = data.batch(0)
     t0 = time.perf_counter()
-    compiled = step.lower(state, data.batch(0)).compile()
+    compiled = step.lower(state, b).compile()
     compile_s = time.perf_counter() - t0
     hlo = compiled.as_text()
 
     losses, step_s = [], []
-    for s in range(steps):
-        b = data.batch(s)
+    for _ in range(steps):
         t0 = time.perf_counter()
         state, metrics = compiled(state, b)
         losses.append(float(metrics["loss"]))  # waits for the step
@@ -291,8 +344,32 @@ def train_phase(
         abs(losses[0] - ln_v) < 0.5,
         f"step-0 loss {losses[0]} not within 0.5 of ln(V)={ln_v:.3f}",
     )
+    require(
+        all(b < a for a, b in zip(losses[1:], losses[2:])),
+        f"loss does not fall once updates start: {losses}",
+    )
     require(devices == {mesh.size}, f"state not on all {mesh.size} devices")
     return result
+
+
+def loss_drop(result: dict) -> float:
+    """How far the loss fell over the steps that updated the weights."""
+    return result["losses"][1] - result["losses"][-1]
+
+
+def require_compressed_close(ref: dict, got: dict, name: str) -> None:
+    """A compressed run starts from the same loss as ``ref`` and its loss
+    falls by ``ref``'s drop to within half of it."""
+    require(
+        got["losses"][0] == ref["losses"][0],
+        f"{name}: step-0 loss {got['losses'][0]} != {ref['losses'][0]}",
+    )
+    d_ref, d_got = loss_drop(ref), loss_drop(got)
+    log(f"{name}: loss drop {d_got} vs uncompressed {d_ref}")
+    require(
+        abs(d_got - d_ref) <= 0.5 * d_ref,
+        f"{name}: loss drop {d_got} not within half of {d_ref}",
+    )
 
 
 def allreduce_phase(
@@ -358,6 +435,7 @@ def four_chip_phases(cfg, mesh, *, seed: int, train_cfg, batch: int,
     rel = float(np.abs(a - b).max() / np.abs(b).max())
     log(f"nap_vs_psum: max relative loss difference {rel}")
     require(rel < 1e-4, f"NAP losses {a} != psum losses {b}")
+    require_compressed_close(train["psum"], train["int8"], "int8")
     out["train"] = train
     out["serve"] = serve_phase(cfg, seed=seed, mesh=mesh, **serve_kw)
     return out
@@ -410,20 +488,21 @@ def main() -> None:
         log(f"phase serve: {time.perf_counter() - t0:.1f}s "
             f"peak_bytes_in_use={peak_bytes()}")
         mesh = make_mesh((1, 1), MESH_AXES)
+        runs = []
         for policy in (comm.CommPolicy(), comm.CommPolicy(compress_bits=8)):
             t0 = time.perf_counter()
-            r = train_phase(
+            runs.append(train_phase(
                 train_cfg, mesh=mesh, seed=args.seed, batch=4, seq=512,
                 sync_cfg=policy,
-            )
-            if policy.compress_bits:
-                require(
-                    r["tpu_custom_calls"] > 0,
-                    "no tpu_custom_call in the compressed step: the "
-                    "transport kernels did not compile for the chip",
-                )
+            ))
             log(f"phase train bits={policy.compress_bits}: "
                 f"{time.perf_counter() - t0:.1f}s")
+        require(
+            runs[1]["tpu_custom_calls"] > 0,
+            "no tpu_custom_call in the compressed step: the transport "
+            "kernels did not compile for the chip",
+        )
+        require_compressed_close(runs[0], runs[1], "int8")
     else:
         mesh = make_mesh((2, 2), MESH_AXES)
         r = four_chip_phases(

@@ -341,8 +341,8 @@ def run_spmd_sweep() -> dict:
         functools.partial(serve_model.init_decode, batch_size=1, max_len=10),
         params_sds,
     )
-    cache_sds = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((b_max,) + s.shape, s.dtype),
+    cache_sds = jax.eval_shape(
+        functools.partial(serve_decode.stack_slot_cache, slots=b_max),
         b1_cache_sds,
     )
     tok_sds = jax.ShapeDtypeStruct((b_max, 1), jnp.int32)

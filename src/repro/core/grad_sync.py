@@ -801,9 +801,7 @@ def make_grad_sync(
             grads, cfg=cfg, inter_axes=inter, intra_axes=intra
         )
 
-    # the Pallas interpreter (CPU) cannot lower under vma checks; the
-    # compiled TPU kernels type their outputs either way
     return jax.shard_map(
         _local, mesh=mesh, in_specs=(grad_specs,), out_specs=grad_specs,
-        check_vma=False,
+        check_vma=transport.vma_checkable(),
     )

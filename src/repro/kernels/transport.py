@@ -55,6 +55,7 @@ from . import ref
 __all__ = [
     "quantize_pack",
     "unpack_dequantize",
+    "vma_checkable",
     "wire_dtype",
     "wire_itemsize",
     "DEFAULT_BLOCK",
@@ -207,9 +208,9 @@ def _blocked_call(kern, name, interpret, base, scales, x3, *, nb: int,
     R, _, half = x3.shape
     tile = min(nb, TILE_BLOCKS)
     L = scales.shape[0]
-    (base2, scales2, x3), vma = _vary_together(
-        _scalar_2d(base), scales.reshape(1, L), x3
-    )
+    base2, scales2 = _scalar_2d(base), scales.reshape(1, L)
+    # the caller's vma, so the call traces under a check_vma shard_map
+    vma = frozenset().union(*(jax.typeof(a).vma for a in (base2, scales2, x3)))
     return pl.pallas_call(
         kern,
         name=name,
@@ -231,17 +232,11 @@ def _blocked_call(kern, name, interpret, base, scales, x3, *, nb: int,
     )(base2, scales2, x3)
 
 
-def _vary_together(*operands):
-    """Cast every operand to vary over the union of the mesh axes any of
-    them varies over (a no-op outside ``shard_map``), so the call traces
-    inside a ``check_vma=True`` shard_map.  Returns the operands and that
-    union, which is also the output's ``vma``."""
-    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
-    out = []
-    for a in operands:
-        missing = tuple(sorted(vma - jax.typeof(a).vma))
-        out.append(lax.pcast(a, missing, to="varying") if missing else a)
-    return out, vma
+def vma_checkable() -> bool:
+    """Whether a ``shard_map`` that runs these kernels can keep jax's
+    ``check_vma``: yes where they compile; the CPU Pallas interpreter
+    cannot lower under it."""
+    return not _on_cpu()
 
 
 def quantize_pack(

@@ -169,12 +169,17 @@ def attention_full(
 
 
 def init_cache(cfg, batch: int, max_len: int, *, window: int | None, dtype):
-    """Cache pytree for one attention sublayer."""
+    """Cache pytree for one attention sublayer.
+
+    Keys and values are stored ``(batch, kv_heads, head_dim, size)``:
+    positions on the minor axis fill the TPU's 128 lanes, where a
+    64-wide head dimension there would be padded to twice its size.
+    """
     size = min(max_len, window) if window else max_len
     hd = cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((batch, cfg.num_kv_heads, size, hd), dtype),
-        "v": jnp.zeros((batch, cfg.num_kv_heads, size, hd), dtype),
+        "k": jnp.zeros((batch, cfg.num_kv_heads, hd, size), dtype),
+        "v": jnp.zeros((batch, cfg.num_kv_heads, hd, size), dtype),
         "pos": jnp.full((size,), -1, jnp.int32),
     }
 
@@ -214,10 +219,10 @@ def attention_decode(
     q, k_new, v_new = _project_qkv(
         params, x, x, cfg, positions, positions, rope=True
     )
-    size = cache["k"].shape[2]
+    size = cache["k"].shape[3]
     slot = index % size
-    k = lax.dynamic_update_slice_in_dim(cache["k"], jnp.swapaxes(k_new, 1, 2), slot, axis=2)
-    v = lax.dynamic_update_slice_in_dim(cache["v"], jnp.swapaxes(v_new, 1, 2), slot, axis=2)
+    k = lax.dynamic_update_slice_in_dim(cache["k"], jnp.transpose(k_new, (0, 2, 3, 1)), slot, axis=3)
+    v = lax.dynamic_update_slice_in_dim(cache["v"], jnp.transpose(v_new, (0, 2, 3, 1)), slot, axis=3)
     pos = lax.dynamic_update_slice_in_dim(
         cache["pos"], index[None].astype(jnp.int32), slot, axis=0
     )
@@ -228,7 +233,7 @@ def attention_decode(
     q = q.reshape(B, 1, cfg.num_kv_heads, G, hd)
     scale = 1.0 / math.sqrt(hd)
     scores = jnp.einsum(
-        "bqkgh,bksh->bkgqs", q, k, preferred_element_type=jnp.float32
+        "bqkgh,bkhs->bkgqs", q, k, preferred_element_type=jnp.float32
     ) * scale
     scores = softcap(scores, cfg.attn_logit_softcap)
     valid = (pos >= 0) & (pos <= index)
@@ -237,7 +242,7 @@ def attention_decode(
     scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(
-        "bkgqs,bksh->bqkgh", probs.astype(v.dtype), v,
+        "bkgqs,bkhs->bqkgh", probs.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     ).astype(x.dtype)
     out = out.reshape(B, 1, cfg.num_heads * hd)

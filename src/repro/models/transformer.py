@@ -274,19 +274,36 @@ def stack_decode(
     pattern=None,
     enc_out: jax.Array | None = None,
 ):
-    """One-token decode through the scanned stack: returns (x, new cache)."""
+    """One-token decode through the scanned stack: returns (x, new cache).
+
+    The cache rides the scan carry and each layer's entry is written
+    back in place.  Scanning it as ``xs``/``ys`` instead would build the
+    new cache in a second buffer the size of the whole cache, and a
+    batching transform (the serving engine's slot ``vmap``) would also
+    transpose it into layer-major order and back on every step.
+    """
     pattern = pattern if pattern is not None else cfg.pattern
 
-    def body(h, xs):
-        layer_params, layer_cache = xs
+    def body(carry, layer_params):
+        h, cache, layer = carry
         h = policy.act(h, kind="hidden")
+        layer_cache = jax.tree.map(
+            lambda c: lax.dynamic_index_in_dim(c, layer, keepdims=False),
+            cache,
+        )
         new_cache = {}
         for i, sub in enumerate(pattern):
             h, new_cache[f"sub{i}"] = _sublayer_decode(
                 layer_params[f"sub{i}"], h, layer_cache[f"sub{i}"], sub,
                 cfg=cfg, policy=policy, index=index, enc_out=enc_out,
             )
-        return h, new_cache
+        cache = jax.tree.map(
+            lambda c, n: lax.dynamic_update_index_in_dim(c, n, layer, 0),
+            cache, new_cache,
+        )
+        return (h, cache, layer + 1), None
 
-    x, new_cache = lax.scan(body, x, (stack_params, cache))
+    (x, new_cache, _), _ = lax.scan(
+        body, (x, cache, jnp.zeros((), jnp.int32)), stack_params
+    )
     return x, new_cache

@@ -45,6 +45,8 @@ __all__ = [
     "make_decode_slice",
     "make_decode_loop",
     "greedy_step",
+    "slot_axes",
+    "stack_slot_cache",
 ]
 
 
@@ -185,15 +187,45 @@ def greedy_step(model, ctx: comm.CommContext | None = None):
 # ---------------------------------------------------------------------------
 
 
-def _vmapped_decode_hidden(model):
-    """``decode_hidden`` over a slot-stacked cache: every leaf carries a
-    leading slot axis over an inner B=1 cache; tokens are ``(slots, 1)``.
+def slot_axes(cache) -> dict:
+    """Where the slot axis sits in each top-level entry of a slot-stacked
+    cache (a pytree prefix, usable as vmap axes).
+
+    The layer-stacked ``stack`` leaves keep their layer axis first and
+    take the slot axis second: that is the order the layer scan walks,
+    so the slot ``vmap`` of the scan costs no relayout.  Slot-major
+    stack leaves would be copied into layer-major order and back on
+    every call of the slice.  The other entries (``index``,
+    ``enc_out``) are slot-major.
+    """
+    return {k: 1 if k == "stack" else 0 for k in cache}
+
+
+def stack_slot_cache(b1_cache, slots: int):
+    """Slot-stacked cache holding ``slots`` copies of a B=1 cache, laid
+    out as :func:`slot_axes` says."""
+
+    def stack(x, ax):
+        x = jnp.expand_dims(x, ax)
+        return jnp.broadcast_to(x, x.shape[:ax] + (slots,) + x.shape[ax + 1:])
+
+    return {
+        k: jax.tree.map(lambda x, ax=ax: stack(x, ax), b1_cache[k])
+        for k, ax in slot_axes(b1_cache).items()
+    }
+
+
+def _vmapped_decode_hidden(model, cache_axes: dict):
+    """``decode_hidden`` over a slot-stacked cache (slot axes
+    ``cache_axes``, inner B=1 caches); tokens are ``(slots, 1)``.
     Returns ``(hidden (slots, 1, D), new stacked cache)``."""
 
     def one(params, cache, tok):
         return model.decode_hidden(params, cache, tok[None])  # B=1
 
-    return jax.vmap(one, in_axes=(None, 0, 0))
+    return jax.vmap(
+        one, in_axes=(None, cache_axes, 0), out_axes=(0, cache_axes)
+    )
 
 
 def make_decode_slice(
@@ -206,7 +238,8 @@ def make_decode_slice(
     """Build the jitted decode slice
     ``(params, cache, tok, active) -> (out, tok', cache', steps)``.
 
-    ``cache`` is slot-stacked (leading slot axis, inner B=1), ``tok`` is
+    ``cache`` is slot-stacked (inner B=1 caches, slot axes as
+    :func:`slot_axes` says), ``tok`` is
     ``(slots, 1)`` int32 — the next token to feed — and ``active`` is
     ``(slots,)`` bool slot occupancy.  The slice records up to
     ``slice_len`` tokens per slot into ``out (slots, slice_len)``
@@ -222,11 +255,11 @@ def make_decode_slice(
     slices — the continuous-batching boundary — by scattering fresh B=1
     prefill caches into slot rows; this function never resizes.
     """
-    decode_hidden = _vmapped_decode_hidden(model)
     head = make_tp_head(model, ctx)
 
     def slice_fn(params, cache, tok, active):
         slots = tok.shape[0]
+        decode_hidden = _vmapped_decode_hidden(model, slot_axes(cache))
         out0 = jnp.zeros((slots, slice_len), jnp.int32)
         done0 = ~active
         stop0 = jnp.zeros((), jnp.float32)

@@ -4,9 +4,11 @@ One engine owns one model replica's device state and drives it with the
 host-side :class:`repro.serve.scheduler.Scheduler`:
 
 * **slot-stacked caches** — the decode cache is a pytree whose every
-  leaf carries a leading *slot* axis over an inner B=1 cache, so
-  membership changes are per-row scatters (``full.at[slot].set(one)``)
-  and the decode batch shape never retraces;
+  leaf carries a *slot* axis over an inner B=1 cache (after the layer
+  axis on the layer-stacked leaves: see
+  :func:`repro.serve.decode.slot_axes`), so membership changes are
+  per-row scatters and the decode batch shape never retraces; the slice
+  and the scatters donate the cache, so it is never held twice;
 * **bucketed prefill** — each admitted request prefills alone (B=1) in
   a jitted program compiled per *bucket length*, not per prompt length:
   the prompt rides padded to its :class:`PromptBuckets` bucket and a
@@ -116,6 +118,7 @@ class ServeEngine:
 
         # -- device state --------------------------------------------------
         self._cache = self._init_slot_cache()
+        self._cache_axes = _decode.slot_axes(self._cache)
         self._tok = jnp.zeros((self.padded_slots, 1), jnp.int32)
         self._active = jnp.zeros((self.padded_slots,), bool)
 
@@ -124,16 +127,22 @@ class ServeEngine:
         self._slice = self._build_slice()
         # stacked leaf rows have exactly the B=1 leaf's shape, so the
         # scatter is a plain per-row set on every leaf
-        self._scatter = jax.jit(
-            lambda full, one, row: jax.tree.map(
-                lambda f, o: f.at[row].set(o), full, one
-            )
-        )
+        def scatter(full, one, row):
+            return {
+                k: jax.tree.map(
+                    lambda f, o, ax=ax: f.at[(slice(None),) * ax + (row,)].set(o),
+                    full[k], one[k],
+                )
+                for k, ax in self._cache_axes.items()
+            }
+
+        self._scatter = jax.jit(scatter, donate_argnums=(0,))
         self._set_tok = jax.jit(
             lambda tok, active, row, t: (
                 tok.at[row, 0].set(t),
                 active.at[row].set(True),
-            )
+            ),
+            donate_argnums=(0, 1),
         )
 
         # -- accounting ----------------------------------------------------
@@ -151,25 +160,30 @@ class ServeEngine:
         )
 
     def _init_slot_cache(self):
-        """Slot-stacked cache: every leaf gets a leading slot axis over
-        an inner B=1 cache (the scalar ``index`` becomes ``(slots,)``).
-        With a mesh it is created already sharded over the slot axis."""
+        """Slot-stacked cache: every leaf gets a slot axis over an inner
+        B=1 cache (the scalar ``index`` becomes ``(slots,)``).  With a
+        mesh it is created already sharded over the slot axis."""
 
         def init(params):
             b1 = self.model.init_decode(
                 params, 1, max_len=self.max_len, batch=self._b1_extras()
             )
-            return jax.tree.map(
-                lambda x: jnp.broadcast_to(
-                    x[None], (self.padded_slots,) + x.shape
-                ),
-                b1,
-            )
+            return _decode.stack_slot_cache(b1, self.padded_slots)
 
         if self.mesh is None:
-            return init(self.params)
-        slots = NamedSharding(self.mesh, P(self.ctx.topology.axes))
-        return jax.jit(init, out_shardings=slots)(self.params)
+            return jax.jit(init)(self.params)
+        specs = self._slot_specs(jax.eval_shape(init, self.params))
+        shardings = {k: NamedSharding(self.mesh, p) for k, p in specs.items()}
+        return jax.jit(init, out_shardings=shardings)(self.params)
+
+    def _slot_specs(self, cache) -> dict:
+        """``PartitionSpec`` per cache entry sharding its slot axis over
+        the serving group (a pytree prefix of the cache)."""
+        joint = self.ctx.topology.axes
+        return {
+            k: P(*(None,) * ax, joint)
+            for k, ax in _decode.slot_axes(cache).items()
+        }
 
     # -- compiled programs ---------------------------------------------------
 
@@ -178,19 +192,22 @@ class ServeEngine:
             self.model, self._slice_ctx,
             slice_len=self.slice_len, eos_id=self.eos_id,
         )
+        # the slice replaces the cache and the carry token
+        donate = (1, 2)
         if self.mesh is None:
-            return jax.jit(slice_fn)
+            return jax.jit(slice_fn, donate_argnums=donate)
         joint = self.ctx.topology.axes
-        spec = P(joint)  # pytree prefix: shard the leading slot axis
+        spec = P(joint)
+        cache_spec = self._slot_specs(self._cache)
         fn = jax.shard_map(
             slice_fn,
             mesh=self.mesh,
-            in_specs=(P(), spec, spec, spec),
+            in_specs=(P(), cache_spec, spec, spec),
             # the step count is group-agreed (early exit is min-reduced)
-            out_specs=(spec, spec, spec, P()),
+            out_specs=(spec, spec, cache_spec, P()),
             check_vma=False,
         )
-        return jax.jit(fn)
+        return jax.jit(fn, donate_argnums=donate)
 
     def _prefill_fn(self, bucket_len: int, extras_sds):
         """Jitted B=1 bucketed prefill: ``(params, prompt (1, L), n_real

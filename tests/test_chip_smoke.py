@@ -40,19 +40,59 @@ def test_serve_phase_agrees_with_serial_and_serve_batch(cfg):
     assert r["continuous_eq_serial"] and r["serial_eq_serve_batch"]
     assert r["max_greedy_margin"] == 0.0
     assert r["dispatch"] == {}  # no mesh: no decode collectives
+    assert min(r["fault_margin"].values()) > chip_smoke.GREEDY_MARGIN_TOL
 
 
-@pytest.mark.parametrize("bits", [None, 8])
-def test_train_phase_on_one_device_mesh(cfg, bits):
+def test_serve_check_catches_an_off_by_one_prefill(cfg, monkeypatch):
+    # plant a fault the serial run shares: the engine prefills all but
+    # the last prompt token, so its cache index is one short
+    from repro.serve.engine import ServeEngine
+
+    prefill = ServeEngine._prefill
+
+    def short_prefill(self, req):
+        full = req.prompt
+        req.prompt = full[:-1]
+        try:
+            return prefill(self, req)
+        finally:
+            req.prompt = full
+
+    monkeypatch.setattr(ServeEngine, "_prefill", short_prefill)
+    with pytest.raises(chip_smoke.SmokeFailure, match="not greedy"):
+        chip_smoke.serve_phase(cfg, seed=0, **SERVE_KW)
+
+
+def _train_one_device(cfg, bits):
     from repro.core import comm
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1, 1), chip_smoke.MESH_AXES)
-    r = chip_smoke.train_phase(
+    return chip_smoke.train_phase(
         cfg, mesh=mesh, seed=0, batch=2, seq=32,
         sync_cfg=comm.CommPolicy(compress_bits=bits),
     )
-    assert len(r["losses"]) == 3 and r["state_devices"] == [1]
+
+
+@pytest.mark.parametrize("bits", [None, 8])
+def test_train_phase_on_one_device_mesh(cfg, bits):
+    r = _train_one_device(cfg, bits)
+    assert len(r["losses"]) == 4 and r["state_devices"] == [1]
+    # step 0 runs at lr 0 (warmup from 0): the batch's loss is unchanged
+    assert r["losses"][1] == r["losses"][0]
+
+
+def test_int8_loss_drop_close_to_uncompressed(cfg):
+    ref, got = _train_one_device(cfg, None), _train_one_device(cfg, 8)
+    chip_smoke.require_compressed_close(ref, got, "int8")
+
+
+def test_train_check_rejects_a_rising_loss(cfg, monkeypatch):
+    # Adam's sign-like first steps overshoot at a large lr; the phase
+    # must fail rather than report finite losses as a pass
+    monkeypatch.setattr(chip_smoke, "TRAIN_LR", 3.0)
+    with pytest.raises(chip_smoke.SmokeFailure, match="does not fall"):
+        _train_one_device(cfg, None)
 
 
 def test_four_chip_phases_on_virtual_devices(cfg):

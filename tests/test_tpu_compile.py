@@ -96,3 +96,27 @@ def test_compressed_sync_grads_compiles_on_2x2(mesh22, monkeypatch, bits):
     # 4 kernels per bucket: quantize, unpack on receive, requantize,
     # unpack after the gather
     assert hlo.count("tpu_custom_call") >= 4
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_make_grad_sync_compiles_with_vma_checks(mesh22, monkeypatch, bits):
+    """The library's grad-sync ``shard_map`` keeps ``check_vma`` where
+    the kernels compile: the kernels' outputs carry their operands'
+    ``vma``, so the packed path traces and compiles under the checks."""
+    from repro.core import grad_sync
+
+    monkeypatch.setattr(transport, "_on_cpu", lambda: False)
+    assert transport.vma_checkable()
+    spec = P(("pod", "data"))
+    sync = grad_sync.make_grad_sync(
+        comm.CommPolicy(algorithm="nap", compress_bits=bits), mesh22,
+        data_axes=("pod", "data"), grad_specs={"w": spec, "b": spec},
+    )
+    stacked = NamedSharding(mesh22, spec)
+    grads = {
+        "w": jax.ShapeDtypeStruct((4, 2304, 1024), jnp.bfloat16,
+                                  sharding=stacked),
+        "b": jax.ShapeDtypeStruct((4, 2304), jnp.float32, sharding=stacked),
+    }
+    hlo = jax.jit(sync).lower(grads).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 4
