@@ -16,7 +16,7 @@ Default (one chip): ``minicpm-2b`` at its published widths (d_model
   on one fixed batch, once uncompressed and once with int8 transport
   (the Pallas transport kernels, compiled: ``tpu_custom_call`` must be
   in the step's HLO).  Step 0 must sit within 0.5 of ln(vocab) and the
-  loss must fall once updates start.
+  loss must fall over the steps that update.
 
 ``--chips 4`` (one v5e:2x2 host) runs only what exists across chips:
 the allreduce engines against a NumPy sum, NAP-synced training against
@@ -65,9 +65,12 @@ ARCH = "minicpm-2b"
 # replicated params + Adam moments are ~12 B/param: 8 of the 40 layers
 # (771M params with the 122,753 x 2304 embedding) fill ~11 GB of 16.
 # Error-feedback residuals add 4 B/param per chip, so the four-chip
-# run, which trains with them, keeps 6 layers (649M params).
+# run, which trains with them, keeps 4 layers (527M params): its
+# int4+EF step compiled for a described v5e:2x2 needs 6.87 GiB of
+# arguments and 5.00 GiB of temporaries per chip (6 layers: 8.46 +
+# 5.91 GiB, too close to the 16 GiB chip).
 TRAIN_LAYERS = 8
-TRAIN_LAYERS_4CHIP = 6
+TRAIN_LAYERS_4CHIP = 4
 MESH_AXES = ("pod", "data")
 
 
@@ -107,6 +110,24 @@ def peak_bytes() -> int | None:
     """Peak device memory of device 0, where the backend reports it."""
     stats = jax.devices()[0].memory_stats()
     return None if stats is None else int(stats["peak_bytes_in_use"])
+
+
+def live_bytes() -> int:
+    """Bytes of device arrays still alive in this process."""
+    return sum(x.nbytes for x in jax.live_arrays())
+
+
+def require_released(phase: str) -> None:
+    """A finished phase holds no device memory: what it leaves alive
+    (the serving engine's params and cache are ~8 GB at full depth)
+    is missing from the next phase's budget.  The phases share no
+    compiled program, so their executables are dropped too."""
+    jax.clear_caches()
+    held = live_bytes()
+    stats = jax.devices()[0].memory_stats() or {}
+    log(f"after {phase}: live array bytes {held}, "
+        f"device bytes_in_use {stats.get('bytes_in_use')}")
+    require(held == 0, f"{phase} left {held} bytes of device arrays alive")
 
 
 def _gaps(logits, ids):
@@ -217,6 +238,9 @@ def serve_phase(
             jax.tree.leaves(engine.params)[0].sharding.device_set
         ),
     }
+    # the references below hold their own caches: free the engine's
+    # (2.8 GiB at 8 x 1024) so the phase peaks below the chip's 16 GiB
+    del engine
     if mesh is None:
         t0 = time.perf_counter()
         ref = [
@@ -291,7 +315,10 @@ def train_phase(
     ``mesh`` with ``batch`` sequences per chip, all on one batch.  The
     warmup starts at lr 0, so step 0 updates nothing and every later
     step does.  Raises unless the losses are finite, step 0 sits within
-    0.5 of ln(vocab), and the loss falls at every step from step 1 on."""
+    0.5 of ln(vocab), and the last loss is below step 1's (the loss
+    before the first update).  Not at every step: on the chip the first
+    update took 2.57 nats off and the next put 0.02 back (Adam's
+    sign-like early steps overshoot on one fixed batch)."""
     opt_cfg = OptimizerConfig(
         lr=TRAIN_LR, schedule="constant", warmup_steps=1
     )
@@ -345,8 +372,8 @@ def train_phase(
         f"step-0 loss {losses[0]} not within 0.5 of ln(V)={ln_v:.3f}",
     )
     require(
-        all(b < a for a, b in zip(losses[1:], losses[2:])),
-        f"loss does not fall once updates start: {losses}",
+        losses[-1] < losses[1],
+        f"loss does not fall over the updating steps: {losses}",
     )
     require(devices == {mesh.size}, f"state not on all {mesh.size} devices")
     return result
@@ -418,6 +445,7 @@ def four_chip_phases(cfg, mesh, *, seed: int, train_cfg, batch: int,
     """The ``--chips 4`` run: collectives, NAP-vs-psum training, int8 and
     int4+EF transport, and meshed serving, all on ``mesh``."""
     out = {"allreduce": allreduce_phase(mesh, seed=seed)}
+    require_released("allreduce")
     train = {}
     for name, policy in (
         ("psum", comm.CommPolicy(algorithm="psum")),
@@ -431,11 +459,14 @@ def four_chip_phases(cfg, mesh, *, seed: int, train_cfg, batch: int,
             train_cfg, mesh=mesh, seed=seed, batch=batch, seq=seq,
             sync_cfg=policy,
         )
+        require_released(f"train {name}")
     a, b = np.array(train["nap"]["losses"]), np.array(train["psum"]["losses"])
     rel = float(np.abs(a - b).max() / np.abs(b).max())
     log(f"nap_vs_psum: max relative loss difference {rel}")
     require(rel < 1e-4, f"NAP losses {a} != psum losses {b}")
-    require_compressed_close(train["psum"], train["int8"], "int8")
+    # against NAP, which also reduces the int8 run's loss: psum sums the
+    # per-chip losses in another order (1 ulp apart at step 0 on a chip)
+    require_compressed_close(train["nap"], train["int8"], "int8")
     out["train"] = train
     out["serve"] = serve_phase(cfg, seed=seed, mesh=mesh, **serve_kw)
     return out
@@ -487,6 +518,7 @@ def main() -> None:
         serve_phase(cfg, seed=args.seed)
         log(f"phase serve: {time.perf_counter() - t0:.1f}s "
             f"peak_bytes_in_use={peak_bytes()}")
+        require_released("serve")
         mesh = make_mesh((1, 1), MESH_AXES)
         runs = []
         for policy in (comm.CommPolicy(), comm.CommPolicy(compress_bits=8)):
@@ -497,6 +529,7 @@ def main() -> None:
             ))
             log(f"phase train bits={policy.compress_bits}: "
                 f"{time.perf_counter() - t0:.1f}s")
+            require_released(f"train bits={policy.compress_bits}")
         require(
             runs[1]["tpu_custom_calls"] > 0,
             "no tpu_custom_call in the compressed step: the transport "

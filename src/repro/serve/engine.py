@@ -126,14 +126,19 @@ class ServeEngine:
         self._prefills: dict[Any, Callable] = {}  # bucket key -> jitted fn
         self._slice = self._build_slice()
         # stacked leaf rows have exactly the B=1 leaf's shape, so the
-        # scatter is a plain per-row set on every leaf
+        # scatter is a plain per-row set on every leaf.  The compiled
+        # programs close over locals, never over ``self``: an engine in a
+        # reference cycle would hold its params and cache on the device
+        # until the cyclic collector happens to run.
+        cache_axes = self._cache_axes
+
         def scatter(full, one, row):
             return {
                 k: jax.tree.map(
                     lambda f, o, ax=ax: f.at[(slice(None),) * ax + (row,)].set(o),
                     full[k], one[k],
                 )
-                for k, ax in self._cache_axes.items()
+                for k, ax in cache_axes.items()
             }
 
         self._scatter = jax.jit(scatter, donate_argnums=(0,))
@@ -220,11 +225,11 @@ class ServeEngine:
         true prompt produces — and there is exactly one compiled trace
         per bucket length.
         """
-        model = self.model
+        model, max_len = self.model, self.max_len
 
         def prefill(params, prompt, n_real, extras):
             cache = model.init_decode(
-                params, 1, max_len=self.max_len, batch=extras
+                params, 1, max_len=max_len, batch=extras
             )
             logits0 = jnp.zeros((1, 1, model.cfg.vocab_size), jnp.float32)
 

@@ -43,6 +43,22 @@ def test_serve_phase_agrees_with_serial_and_serve_batch(cfg):
     assert min(r["fault_margin"].values()) > chip_smoke.GREEDY_MARGIN_TOL
 
 
+def test_serve_phase_releases_device_memory_without_gc(cfg):
+    # the one-chip run trains right after serving: whatever the serve
+    # phase leaves alive (until the cyclic collector runs) is taken from
+    # the train phase's device memory
+    import gc
+
+    gc.collect()
+    before = chip_smoke.live_bytes()
+    gc.disable()
+    try:
+        chip_smoke.serve_phase(cfg, seed=0, **SERVE_KW)
+        assert chip_smoke.live_bytes() == before
+    finally:
+        gc.enable()
+
+
 def test_serve_check_catches_an_off_by_one_prefill(cfg, monkeypatch):
     # plant a fault the serial run shares: the engine prefills all but
     # the last prompt token, so its cache index is one short

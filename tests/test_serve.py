@@ -652,3 +652,36 @@ def test_engine_extras_template_is_enforced():
     engine = ServeEngine(model, params, num_slots=1, max_len=8)
     with pytest.raises(ValueError):
         engine.submit([1], 1, extras={"frames": None})
+
+
+def test_dropped_engine_frees_its_device_state_without_gc():
+    # the engine's compiled programs must not close over the engine: in
+    # a reference cycle its params and cache stay on the device until
+    # the cyclic collector runs (a one-chip run that serves and then
+    # trains ran out of device memory that way)
+    import gc
+    import weakref
+
+    import jax
+
+    from repro.configs import get_config, reduced
+    from repro.models import build_model
+    from repro.serve import PromptBuckets, ServeEngine
+
+    cfg = reduced(get_config("minicpm-2b"))
+    model = build_model(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    engine = ServeEngine(
+        model, params, num_slots=2, max_len=16, buckets=PromptBuckets([4])
+    )
+    engine.submit([3, 1, 4], 3)
+    engine.run()
+    refs = [weakref.ref(engine)] + [
+        weakref.ref(x) for x in jax.tree.leaves(engine._cache)
+    ]
+    gc.disable()
+    try:
+        del engine
+        assert all(r() is None for r in refs)
+    finally:
+        gc.enable()
