@@ -54,6 +54,7 @@ import numpy as np
 from jax import lax
 
 from . import napalg
+from ..runtime.tracing import scope
 
 __all__ = [
     "nap_allreduce",
@@ -198,7 +199,8 @@ def nap_allreduce(
     sched = napalg.build_nap_schedule(n, ppn)
     joint = inter + intra
 
-    v = named_reduce(x, intra)
+    with scope("intra", phase=True):
+        v = named_reduce(x, intra)
     if not sched.steps:
         return v
     chip = _chip_index(inter, intra)
@@ -214,11 +216,15 @@ def nap_allreduce(
     for step, (rmasks, smask) in zip(
         sched.steps, napalg.step_mask_tables(n, ppn)
     ):
-        acc = jnp.where(_mask_lookup(smask, chip), v, ident)
-        for rnd, rmask in zip(step.rounds, rmasks):
-            recv = lax.ppermute(v, joint, rnd)
-            acc = jnp.where(_mask_lookup(rmask, chip), fold(acc, recv), acc)
-        v = named_reduce(acc, intra)
+        with scope("inter", phase=True):
+            acc = jnp.where(_mask_lookup(smask, chip), v, ident)
+            for rnd, rmask in zip(step.rounds, rmasks):
+                recv = lax.ppermute(v, joint, rnd)
+                acc = jnp.where(
+                    _mask_lookup(rmask, chip), fold(acc, recv), acc
+                )
+        with scope("intra", phase=True):
+            v = named_reduce(acc, intra)
     return v
 
 
@@ -441,21 +447,25 @@ def _mla_one_chunk(
             [flat, jnp.full((pad,), _op_identity(op, flat.dtype))]
         )
     tiles = flat.reshape(ppn, -1)
-    if op == "sum":
-        # phase 1: stripe the pod partial across local ranks
-        stripe = lax.psum_scatter(
-            flat, intra, scatter_dimension=0, tiled=True
-        )
-    else:
-        gathered = lax.all_to_all(
-            tiles[:, None, :], intra, split_axis=0, concat_axis=1, tiled=False
-        )
-        stripe = _AXIS_REDUCERS[op](gathered[0], axis=0)
+    # phase 1: stripe the pod partial across local ranks
+    with scope("intra", phase=True):
+        if op == "sum":
+            stripe = lax.psum_scatter(
+                flat, intra, scatter_dimension=0, tiled=True
+            )
+        else:
+            gathered = lax.all_to_all(
+                tiles[:, None, :], intra, split_axis=0, concat_axis=1,
+                tiled=False,
+            )
+            stripe = _AXIS_REDUCERS[op](gathered[0], axis=0)
     # phase 2: per-lane RS+AG across the slow domain (ppn parallel lanes)
     if n > 1:
-        stripe = rabenseifner_allreduce(stripe, axes=inter, op=op)
+        with scope("inter", phase=True):
+            stripe = rabenseifner_allreduce(stripe, axes=inter, op=op)
     # phase 3: rebuild the full payload inside the pod
-    out = lax.all_gather(stripe, intra, axis=0, tiled=True)
+    with scope("intra", phase=True):
+        out = lax.all_gather(stripe, intra, axis=0, tiled=True)
     if pad:
         out = out[: out.size - pad]
     return out
@@ -629,8 +639,10 @@ def mla_reduce_scatter(
     ppn = int(np.prod([lax.axis_size(ax) for ax in intra])) if intra else 1
     n = int(np.prod([lax.axis_size(ax) for ax in inter])) if inter else 1
     flat = x.reshape(-1)
-    stripe = _level_reduce_scatter(flat, intra, ppn, op)
-    return _level_reduce_scatter(stripe, inter, n, op, f32_accum=True)
+    with scope("intra", phase=True):
+        stripe = _level_reduce_scatter(flat, intra, ppn, op)
+    with scope("inter", phase=True):
+        return _level_reduce_scatter(stripe, inter, n, op, f32_accum=True)
 
 
 def mla_allgather(
@@ -656,12 +668,14 @@ def mla_allgather(
         elems = shard.size * n * ppn
     stripe_len = -(-int(elems) // ppn)  # ceil: the intra-RS stripe size
     if n > 1:
-        stripe = lax.all_gather(shard, inter, axis=0, tiled=True)
+        with scope("inter", phase=True):
+            stripe = lax.all_gather(shard, inter, axis=0, tiled=True)
         stripe = stripe[:stripe_len]
     else:
         stripe = shard[:stripe_len]
     if ppn > 1:
-        full = lax.all_gather(stripe, intra, axis=0, tiled=True)
+        with scope("intra", phase=True):
+            full = lax.all_gather(stripe, intra, axis=0, tiled=True)
     else:
         full = stripe
     return full[: int(elems)]

@@ -54,6 +54,7 @@ import jax
 import numpy as np
 
 from . import collectives, napalg, perf_model as pm
+from ..runtime.tracing import scope
 
 __all__ = [
     "Topology",
@@ -1170,7 +1171,9 @@ class CommContext:
     carries the topology's axis names; dispatch decisions are host-side
     and static (payload sizes are trace constants), so the traced
     program contains exactly the schedule the model picked — the same
-    decision the simulator replays and the planner prices.
+    decision the simulator replays and the planner prices.  Each call's
+    ops carry the scope ``repro.comm.<engine>`` (with ``intra`` /
+    ``inter`` phases beneath it), so a profile names them.
     """
 
     topology: Topology
@@ -1251,9 +1254,10 @@ class CommContext:
             nbytes, op, algorithm=algorithm, pipeline_chunks=pipeline_chunks
         )
         spec = self._engine_for(d, op, "allreduce")
-        return spec.execute(
-            x, topology=self.topology, op=op, pipeline_chunks=d.chunks
-        )
+        with scope(f"comm.{spec.name}"):
+            return spec.execute(
+                x, topology=self.topology, op=op, pipeline_chunks=d.chunks
+            )
 
     def reduce_scatter(
         self, x, op: str = "sum", *, algorithm: str | None = None
@@ -1273,7 +1277,8 @@ class CommContext:
             nbytes, op, collective="reduce_scatter", algorithm=algorithm
         )
         spec = self._engine_for(d, op, "reduce_scatter")
-        return spec.execute(x, topology=self.topology, op=op)
+        with scope(f"comm.{spec.name}"):
+            return spec.execute(x, topology=self.topology, op=op)
 
     def allgather(
         self, x, *, elems: int | None = None, algorithm: str | None = None
@@ -1289,7 +1294,8 @@ class CommContext:
             nbytes, "sum", collective="allgather", algorithm=algorithm
         )
         spec = self._engine_for(d, "sum", "allgather")
-        return spec.execute(x, topology=self.topology, elems=total)
+        with scope(f"comm.{spec.name}"):
+            return spec.execute(x, topology=self.topology, elems=total)
 
     # -- gradient sync (inside shard_map) ---------------------------------
 

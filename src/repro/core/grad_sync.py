@@ -67,6 +67,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import bucketing, collectives, comm
 from ..kernels import transport
+from ..runtime.tracing import scope
 
 __all__ = [
     "GradSyncConfig",
@@ -529,45 +530,46 @@ def _execute_plan(leaves, plan, ctx: comm.CommContext, ef=None):
     bits = ctx.policy.compress_bits
     out = [None] * len(leaves)
     new_ef = None if ef is None else list(ef)
-    for bucket in plan.buckets:
-        bctx = _bucket_ctx(ctx, bucket)
-        idxs = bucket.leaves
-        is_float = jnp.issubdtype(leaves[idxs[0]].dtype, jnp.floating)
-        if bits and is_float:
-            # fused + compressed: per-leaf scales (a shared scale would
-            # zero out small-magnitude leaves), mean/dtype per segment
-            parts = []
+    for b, bucket in enumerate(plan.buckets):
+        with scope(f"grad_sync.bucket{b}"):
+            bctx = _bucket_ctx(ctx, bucket)
+            idxs = bucket.leaves
+            is_float = jnp.issubdtype(leaves[idxs[0]].dtype, jnp.floating)
+            if bits and is_float:
+                # fused + compressed: per-leaf scales (a shared scale would
+                # zero out small-magnitude leaves), mean/dtype per segment
+                parts = []
+                for i in idxs:
+                    p = leaves[i].reshape(-1).astype(jnp.float32)
+                    if ef is not None:
+                        p = p + ef[i].reshape(-1)
+                    parts.append(p)
+                segs, scales, err = _compressed_fused_allreduce(
+                    parts, bctx, group, with_err=ef is not None
+                )
+                offs = _leaf_offsets(parts)
+                for k, i in enumerate(idxs):
+                    g = leaves[i]
+                    if ef is not None:
+                        new_ef[i] = err[
+                            offs[k] : offs[k] + g.size
+                        ].reshape(g.shape)
+                    seg = segs[k]
+                    if ctx.policy.mean and group > 1:
+                        seg = seg / group
+                    out[i] = seg.reshape(g.shape).astype(g.dtype)
+                continue
+            if len(idxs) == 1:
+                i = idxs[0]
+                out[i] = _reduce_leaf(leaves[i], bctx, group)
+                continue
+            flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
+            red = _reduce_leaf(flat, bctx, group)
+            off = 0
             for i in idxs:
-                p = leaves[i].reshape(-1).astype(jnp.float32)
-                if ef is not None:
-                    p = p + ef[i].reshape(-1)
-                parts.append(p)
-            segs, scales, err = _compressed_fused_allreduce(
-                parts, bctx, group, with_err=ef is not None
-            )
-            offs = _leaf_offsets(parts)
-            for k, i in enumerate(idxs):
                 g = leaves[i]
-                if ef is not None:
-                    new_ef[i] = err[
-                        offs[k] : offs[k] + g.size
-                    ].reshape(g.shape)
-                seg = segs[k]
-                if ctx.policy.mean and group > 1:
-                    seg = seg / group
-                out[i] = seg.reshape(g.shape).astype(g.dtype)
-            continue
-        if len(idxs) == 1:
-            i = idxs[0]
-            out[i] = _reduce_leaf(leaves[i], bctx, group)
-            continue
-        flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
-        red = _reduce_leaf(flat, bctx, group)
-        off = 0
-        for i in idxs:
-            g = leaves[i]
-            out[i] = red[off : off + g.size].reshape(g.shape)
-            off += g.size
+                out[i] = red[off : off + g.size].reshape(g.shape)
+                off += g.size
     return out, new_ef
 
 

@@ -31,6 +31,7 @@ from ..configs import SHAPES, get_config
 from ..models import ShardingPolicy, build_model
 from ..optim import adamw_init, adamw_update, make_schedule
 from ..configs.base import OptimizerConfig, ShapeConfig
+from ..runtime.tracing import scope
 from .mesh import dp_axes as mesh_dp_axes
 
 __all__ = [
@@ -120,52 +121,53 @@ def make_train_step(
         def loss_fn(p, mb):
             return model.loss(p, mb)
 
-        if n_micro == 1:
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params, batch)
-            grads = _constrain(grads)
-        else:
-            def micro(b):
-                return jax.tree.map(
-                    lambda x: x.reshape((n_micro, -1) + x.shape[1:]), b
-                )
+        with scope("train.fwd_bwd"):
+            if n_micro == 1:
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params, batch)
+                grads = _constrain(grads)
+            else:
+                def micro(b):
+                    return jax.tree.map(
+                        lambda x: x.reshape((n_micro, -1) + x.shape[1:]), b
+                    )
 
-            mbs = micro(batch)
+                mbs = micro(batch)
 
-            def body(carry, mb):
-                acc, lsum = carry
-                (l, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, mb
-                )
-                acc = jax.tree.map(
-                    lambda a, gg: a + gg.astype(jnp.float32), acc, g
-                )
-                return (_constrain(acc), lsum + l), None
+                def body(carry, mb):
+                    acc, lsum = carry
+                    (l, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                        params, mb
+                    )
+                    acc = jax.tree.map(
+                        lambda a, gg: a + gg.astype(jnp.float32), acc, g
+                    )
+                    return (_constrain(acc), lsum + l), None
 
-            zeros = _constrain(
-                jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params
+                zeros = _constrain(
+                    jax.tree.map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), params
+                    )
                 )
+                (grads, lsum), _ = jax.lax.scan(
+                    body, (zeros, jnp.zeros((), jnp.float32)), mbs
+                )
+                grads = jax.tree.map(lambda g: g / n_micro, grads)
+                loss = lsum / n_micro
+                metrics = {"loss": loss}
+        with scope("train.optimizer"):
+            lr = sched(opt.step)
+            new_params, new_opt, om = adamw_update(
+                grads,
+                opt,
+                params,
+                lr=lr,
+                betas=opt_cfg.betas,
+                eps=opt_cfg.eps,
+                weight_decay=opt_cfg.weight_decay,
+                grad_clip=opt_cfg.grad_clip,
             )
-            (grads, lsum), _ = jax.lax.scan(
-                body, (zeros, jnp.zeros((), jnp.float32)), mbs
-            )
-            grads = jax.tree.map(lambda g: g / n_micro, grads)
-            loss = lsum / n_micro
-            metrics = {"loss": loss}
-
-        lr = sched(opt.step)
-        new_params, new_opt, om = adamw_update(
-            grads,
-            opt,
-            params,
-            lr=lr,
-            betas=opt_cfg.betas,
-            eps=opt_cfg.eps,
-            weight_decay=opt_cfg.weight_decay,
-            grad_clip=opt_cfg.grad_clip,
-        )
         out_metrics = {"loss": loss, "lr": lr, **om}
         return {"params": new_params, "opt": new_opt}, out_metrics
 
@@ -216,32 +218,37 @@ def make_dp_train_step(cfg, opt_cfg: OptimizerConfig, mesh, sync_cfg):
 
     def local_step(state, batch):
         params, opt = state["params"], state["opt"]
-        (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(
-            params, batch
-        )
-        if use_ef:
-            ef = jax.tree.map(lambda e: e[0], state["ef"])
-            grads, new_ef = ctx.sync_grads(
-                grads, plan=bucket_plan, ef_state=ef
+        with scope("train.fwd_bwd"):
+            (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(
+                params, batch
             )
-        else:
-            grads = ctx.sync_grads(grads, plan=bucket_plan)
+        with scope("train.grad_sync"):
+            if use_ef:
+                ef = jax.tree.map(lambda e: e[0], state["ef"])
+                grads, new_ef = ctx.sync_grads(
+                    grads, plan=bucket_plan, ef_state=ef
+                )
+            else:
+                grads = ctx.sync_grads(grads, plan=bucket_plan)
         # the paper's canonical workload: single-scalar latency-bound
         # allreduce (loss mean) through the same algorithm
-        if topo.inter_axes:
-            loss = ctx.allreduce(
-                loss,
-                algorithm=sync_cfg.algorithm
-                if sync_cfg.algorithm != "auto" else "nap",
-            ) / group
-        else:
-            loss = jax.lax.pmean(loss, topo.intra_axes)
-        lr = sched(opt.step)
-        new_params, new_opt, om = adamw_update(
-            grads, opt, params,
-            lr=lr, betas=opt_cfg.betas, eps=opt_cfg.eps,
-            weight_decay=opt_cfg.weight_decay, grad_clip=opt_cfg.grad_clip,
-        )
+        with scope("train.loss_sync"):
+            if topo.inter_axes:
+                loss = ctx.allreduce(
+                    loss,
+                    algorithm=sync_cfg.algorithm
+                    if sync_cfg.algorithm != "auto" else "nap",
+                ) / group
+            else:
+                loss = jax.lax.pmean(loss, topo.intra_axes)
+        with scope("train.optimizer"):
+            lr = sched(opt.step)
+            new_params, new_opt, om = adamw_update(
+                grads, opt, params,
+                lr=lr, betas=opt_cfg.betas, eps=opt_cfg.eps,
+                weight_decay=opt_cfg.weight_decay,
+                grad_clip=opt_cfg.grad_clip,
+            )
         new_state = {"params": new_params, "opt": new_opt}
         if use_ef:
             # residuals are per-chip: keep the leading group axis

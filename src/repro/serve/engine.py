@@ -21,6 +21,12 @@ host-side :class:`repro.serve.scheduler.Scheduler`:
   serving group's joint axes with the slot axis sharded and the logits
   head tensor-parallel through ``CommContext`` routing.
 
+Its programs compile under stable names (``jit_prefill``,
+``jit_slice_fn``, ``jit_scatter``, ``jit_set_tok``), :attr:`ServeEngine.
+traces` counts how often each was traced, and :meth:`ServeEngine.step`
+marks its host work with ``repro.serve.*`` spans
+(:mod:`repro.runtime.tracing`).
+
 The slot count is ragged over the serving group
 (:meth:`Scheduler.shard_geometry`, i.e. ``napalg.ragged_splits``); the
 executed lowering pads every chip to ``max(geometry)`` rows — repo
@@ -30,6 +36,7 @@ scheduler simply never fills the pad slots.
 
 from __future__ import annotations
 
+import collections
 import time
 from typing import Any, Callable
 
@@ -39,6 +46,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import comm
+from ..runtime.tracing import span
 from . import decode as _decode
 from .scheduler import PromptBuckets, Request, Scheduler
 
@@ -123,6 +131,9 @@ class ServeEngine:
         self._active = jnp.zeros((self.padded_slots,), bool)
 
         # -- compiled programs ---------------------------------------------
+        # traces of each program ("slice", "prefill/<bucket>", "scatter",
+        # "set_tok"): steady-state steps leave it unchanged
+        self.traces: collections.Counter[str] = collections.Counter()
         self._prefills: dict[Any, Callable] = {}  # bucket key -> jitted fn
         self._slice = self._build_slice()
         # stacked leaf rows have exactly the B=1 leaf's shape, so the
@@ -130,9 +141,10 @@ class ServeEngine:
         # programs close over locals, never over ``self``: an engine in a
         # reference cycle would hold its params and cache on the device
         # until the cyclic collector happens to run.
-        cache_axes = self._cache_axes
+        cache_axes, traces = self._cache_axes, self.traces
 
         def scatter(full, one, row):
+            traces["scatter"] += 1
             return {
                 k: jax.tree.map(
                     lambda f, o, ax=ax: f.at[(slice(None),) * ax + (row,)].set(o),
@@ -141,14 +153,12 @@ class ServeEngine:
                 for k, ax in cache_axes.items()
             }
 
+        def set_tok(tok, active, row, t):
+            traces["set_tok"] += 1
+            return tok.at[row, 0].set(t), active.at[row].set(True)
+
         self._scatter = jax.jit(scatter, donate_argnums=(0,))
-        self._set_tok = jax.jit(
-            lambda tok, active, row, t: (
-                tok.at[row, 0].set(t),
-                active.at[row].set(True),
-            ),
-            donate_argnums=(0, 1),
-        )
+        self._set_tok = jax.jit(set_tok, donate_argnums=(0, 1))
 
         # -- accounting ----------------------------------------------------
         self.step_times: list[tuple[int, float, int]] = []  # fit-shaped rows
@@ -193,10 +203,16 @@ class ServeEngine:
     # -- compiled programs ---------------------------------------------------
 
     def _build_slice(self):
-        slice_fn = _decode.make_decode_slice(
+        decode_slice = _decode.make_decode_slice(
             self.model, self._slice_ctx,
             slice_len=self.slice_len, eos_id=self.eos_id,
         )
+        traces = self.traces
+
+        def slice_fn(params, cache, tok, active):
+            traces["slice"] += 1
+            return decode_slice(params, cache, tok, active)
+
         # the slice replaces the cache and the carry token
         donate = (1, 2)
         if self.mesh is None:
@@ -214,9 +230,9 @@ class ServeEngine:
         )
         return jax.jit(fn, donate_argnums=donate)
 
-    def _prefill_fn(self, bucket_len: int, extras_sds):
-        """Jitted B=1 bucketed prefill: ``(params, prompt (1, L), n_real
-        [, extras]) -> (cache, first token (1,))``.
+    def _prefill_fn(self, bucket_len: int):
+        """Jitted B=1 bucketed prefill: ``(params, prompt (1, L), n_real,
+        extras or None) -> (cache, first token (1,))``.
 
         Teacher-forces the padded prompt through ``decode_step`` inside a
         ``fori_loop``; a scalar ``keep = t < n_real`` where-snapshot on
@@ -225,9 +241,10 @@ class ServeEngine:
         true prompt produces — and there is exactly one compiled trace
         per bucket length.
         """
-        model, max_len = self.model, self.max_len
+        model, max_len, traces = self.model, self.max_len, self.traces
 
         def prefill(params, prompt, n_real, extras):
+            traces[f"prefill/{bucket_len}"] += 1
             cache = model.init_decode(
                 params, 1, max_len=max_len, batch=extras
             )
@@ -252,30 +269,17 @@ class ServeEngine:
             tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
             return cache, tok
 
-        if extras_sds is None:
-            return jax.jit(lambda p, pr, n: prefill(p, pr, n, None))
         return jax.jit(prefill)
 
     def _prefill(self, req: Request):
-        key = (req.bucket_len, req.extras is not None)
-        if key not in self._prefills:
-            extras_sds = (
-                jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                    req.extras,
-                )
-                if req.extras is not None
-                else None
-            )
-            self._prefills[key] = self._prefill_fn(req.bucket_len, extras_sds)
+        if req.bucket_len not in self._prefills:
+            self._prefills[req.bucket_len] = self._prefill_fn(req.bucket_len)
         prompt = np.zeros((1, req.bucket_len), np.int32)
         prompt[0, : len(req.prompt)] = req.prompt
         n_real = jnp.asarray(len(req.prompt), jnp.int32)
-        if req.extras is not None:
-            return self._prefills[key](
-                self.params, jnp.asarray(prompt), n_real, req.extras
-            )
-        return self._prefills[key](self.params, jnp.asarray(prompt), n_real)
+        return self._prefills[req.bucket_len](
+            self.params, jnp.asarray(prompt), n_real, req.extras
+        )
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -322,38 +326,56 @@ class ServeEngine:
         (B=1 bucketed prefill, scattered into slot rows), run one decode
         slice, record the emitted tokens.  Returns requests that
         *finished* during this step.  No-op (returns ``[]``) when idle.
+
+        Host spans: ``serve.admit`` (holding a ``serve.prefill`` with
+        counts ``bucket`` and ``prompt`` per admission), ``serve.slice``
+        (the dispatch), ``serve.wait`` (until the slice's tokens are on
+        the host) and ``serve.record`` (counts ``steps`` and ``tokens``).
         """
         now = self.clock() if now is None else now
-        for req in self.scheduler.admit(now=now):
-            cache_b1, tok0 = self._prefill(req)
-            row = jnp.asarray(req.slot, jnp.int32)
-            self._cache = self._scatter(self._cache, cache_b1, row)
-            self._tok, self._active = self._set_tok(
-                self._tok, self._active, row, tok0[0]
-            )
-        self._sync_active()
+        with span("serve.admit"):
+            for req in self.scheduler.admit(now=now):
+                with span(
+                    "serve.prefill",
+                    bucket=req.bucket_len,
+                    prompt=len(req.prompt),
+                ):
+                    cache_b1, tok0 = self._prefill(req)
+                row = jnp.asarray(req.slot, jnp.int32)
+                self._cache = self._scatter(self._cache, cache_b1, row)
+                self._tok, self._active = self._set_tok(
+                    self._tok, self._active, row, tok0[0]
+                )
+            self._sync_active()
         if not any(self.scheduler.active_mask()):
             return []
 
         t0 = self.clock()
-        out, self._tok, self._cache, steps = self._slice(
-            self.params, self._cache, self._tok, self._active
-        )
-        out = np.asarray(out)
-        steps_run = int(steps)
+        with span("serve.slice"):
+            out, self._tok, self._cache, steps = self._slice(
+                self.params, self._cache, self._tok, self._active
+            )
+        with span("serve.wait"):
+            out = np.asarray(out)
+            steps_run = int(steps)
         t1 = self.clock()
 
         finished: list[Request] = []
-        for t in range(steps_run):
-            for slot in range(self.scheduler.num_slots):
-                # record_token drops tokens for freed/never-filled slots,
-                # so garbage rows and post-EOS columns are no-ops
-                done = self.scheduler.record_token(
-                    slot, int(out[slot, t]), now=t1
-                )
-                if done is not None:
-                    finished.append(done)
-        self._sync_active()
+        with span("serve.record", steps=steps_run) as record:
+            tokens = 0
+            for t in range(steps_run):
+                for slot in range(self.scheduler.num_slots):
+                    # record_token drops tokens for freed/never-filled
+                    # slots, so garbage rows and post-EOS columns are
+                    # no-ops
+                    tokens += self.scheduler.slots[slot] is not None
+                    done = self.scheduler.record_token(
+                        slot, int(out[slot, t]), now=t1
+                    )
+                    if done is not None:
+                        finished.append(done)
+            self._sync_active()
+            record.set_metadata(tokens=tokens)
 
         # MachineParams.fit-shaped measurement row for the logits
         # allreduce this slice ran: (nbytes, seconds-per-step, senders).
