@@ -1,4 +1,5 @@
-"""GQA attention: full-sequence (train/prefill), decode-with-cache, cross.
+"""GQA attention: full-sequence (train), cache-filling prefill, decode-with-cache,
+cross.
 
 Features required by the assigned archs: grouped KV heads (GQA/MQA),
 sliding-window masks (gemma2 local layers), attention-logit soft-capping
@@ -27,6 +28,7 @@ __all__ = [
     "init_attention",
     "attention_full",
     "attention_decode",
+    "attention_prefill",
     "init_cache",
 ]
 
@@ -161,6 +163,39 @@ def attention_full(
         )
     out = out.reshape(B, S, cfg.num_heads * cfg.resolved_head_dim)
     return dense(out, params["w_o"])
+
+
+def attention_prefill(
+    params, x: jax.Array, n_real: jax.Array, *, cfg, policy, size: int
+) -> tuple[jax.Array, dict]:
+    """Global causal self-attention over a padded prompt that also fills
+    the prompt's decode cache.  x: (B, L, D) at positions 0..L-1, of
+    which the first ``n_real`` are real.
+
+    Returns ``(y, cache)`` with the cache laid out as
+    ``init_cache(cfg, B, size, window=None)``: K and V of every position
+    ``t < L`` at slot ``t``, ``pos[t] = t`` for ``t < n_real`` and -1
+    beyond.  The causal mask keeps pad positions out of every real row
+    here, and ``pos = -1`` keeps them out of ``attention_decode``.
+    """
+    B, L, _ = x.shape
+    hd = cfg.resolved_head_dim
+    t = jnp.arange(L)
+    positions = jnp.broadcast_to(t, (B, L))
+    q, k, v = _project_qkv(params, x, x, cfg, positions, positions, rope=True)
+    G = cfg.num_heads // cfg.num_kv_heads
+    q = q.reshape(B, L, cfg.num_kv_heads, G, hd)
+    out = _sdpa_chunk(q, k, v, cfg, t, t, causal=True, window=None)
+    y = dense(out.reshape(B, L, cfg.num_heads * hd), params["w_o"])
+
+    def to_cache(a):  # (B, L, K, hd) -> (B, K, hd, size)
+        a = jnp.pad(jnp.transpose(a, (0, 2, 3, 1)),
+                    ((0, 0), (0, 0), (0, 0), (0, size - L)))
+        return policy.act(a, kind="cache")
+
+    slots = jnp.arange(size, dtype=jnp.int32)
+    pos = jnp.where(slots < n_real, slots, -1)
+    return y, {"k": to_cache(k), "v": to_cache(v), "pos": pos}
 
 
 # ---------------------------------------------------------------------------

@@ -44,6 +44,11 @@ class Model:
     # contraction-sharded head can be computed outside the model.
     decode_hidden: Callable = None  # (params, cache, tokens) -> (hidden, cache)
     head_weights: Callable = None   # (params) -> (D, V)
+    # the whole padded prompt in one causal forward pass, where the stack
+    # has a parallel form (every sublayer global attention with a dense
+    # FFN); None where a prompt must be stepped through ``decode_step``.
+    prefill: Callable = None  # (params, prompt (1, L), n_real, max_len)
+    #                           -> (cache, last logits (1, V) f32)
 
 
 def _dtype(cfg):
@@ -233,16 +238,45 @@ def build_model(cfg, policy: ShardingPolicy | None = None) -> Model:
         new_cache = dict(cache, index=index + 1, stack=new_stack)
         return x, new_cache
 
-    def decode_step(params, cache, tokens):
-        """tokens: (B, 1) int32 (or (B, 1, D) embeds for VLM stubs)."""
-        x, new_cache = decode_hidden(params, cache, tokens)
+    def head(params, x):
         logits = jnp.einsum(
             "bsd,dv->bsv", x, _head_weights(params, cfg).astype(x.dtype),
             preferred_element_type=jnp.float32,
         )
         logits = softcap(logits, cfg.final_logit_softcap)
-        logits = policy.act(logits, kind="logits")
-        return logits, new_cache
+        return policy.act(logits, kind="logits")
+
+    def decode_step(params, cache, tokens):
+        """tokens: (B, 1) int32 (or (B, 1, D) embeds for VLM stubs)."""
+        x, new_cache = decode_hidden(params, cache, tokens)
+        return head(params, x), new_cache
+
+    def prefill(params, prompt, n_real, max_len):
+        """prompt: (1, L) int32, its first ``n_real`` tokens real.
+
+        The cache ``decode_step`` leaves after the ``n_real`` real
+        tokens (index ``n_real``; pad rows hold ``pos = -1``) and the
+        logits (1, V) at the last of them, from one causal forward pass
+        over all L positions.  The head runs on that one row only.
+        """
+        L = prompt.shape[1]
+        if L > max_len:
+            raise ValueError(f"prompt of {L} positions exceeds max_len {max_len}")
+        x = policy.act(_embed_tokens(params, prompt, cfg), kind="hidden")
+        x, stack = tfm.stack_prefill(
+            params["stack"], x, n_real, cfg=cfg, policy=policy, size=max_len
+        )
+        x = lax.dynamic_slice_in_dim(x, n_real - 1, 1, axis=1)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        cache = {"index": jnp.asarray(n_real, jnp.int32), "stack": stack}
+        return cache, head(params, x)[:, 0]
+
+    parallel = (
+        cfg.is_decoder_only
+        and not cfg.cross_attention
+        and not cfg.sandwich_norm
+        and all(s.mixer == "attn" and s.ffn == "dense" for s in cfg.pattern)
+    )
 
     return Model(
         cfg=cfg,
@@ -255,4 +289,5 @@ def build_model(cfg, policy: ShardingPolicy | None = None) -> Model:
         decode_step=decode_step,
         decode_hidden=decode_hidden,
         head_weights=lambda params: _head_weights(params, cfg),
+        prefill=prefill if parallel else None,
     )

@@ -31,6 +31,7 @@ __all__ = [
     "init_stack",
     "stack_apply",
     "stack_decode",
+    "stack_prefill",
     "init_stack_cache",
 ]
 
@@ -178,6 +179,34 @@ def stack_apply(
     body = _remat_wrap(body, cfg.remat)
     (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)), stack_params)
     return x, aux
+
+
+def stack_prefill(
+    stack_params, x: jax.Array, n_real: jax.Array, *, cfg, policy, size: int
+):
+    """Causal forward of a padded prompt through the scanned stack that
+    also fills its decode cache: returns ``(x, cache)``, the cache laid
+    out as ``init_stack_cache(cfg, B, size, ...)`` after ``n_real`` real
+    positions (see ``attention_prefill``).  Only for stacks whose every
+    sublayer is global ``"attn"`` with a dense FFN.
+    """
+
+    def body(h, layer_params):
+        h = policy.act(h, kind="hidden")
+        cache = {}
+        for i in range(len(cfg.pattern)):
+            p = layer_params[f"sub{i}"]
+            a, cache[f"sub{i}"] = attn_mod.attention_prefill(
+                p["mixer"], rms_norm(h, p["norm1"], cfg.norm_eps), n_real,
+                cfg=cfg, policy=policy, size=size,
+            )
+            h = h + a
+            h = h + glu_mlp(
+                p["ffn"], rms_norm(h, p["norm2"], cfg.norm_eps), cfg.act
+            )
+        return h, cache
+
+    return lax.scan(body, x, stack_params)
 
 
 # ---------------------------------------------------------------------------

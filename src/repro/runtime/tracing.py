@@ -39,10 +39,11 @@ __all__ = ["span", "scope"]
 ROOT = "repro."
 
 
-def span(name: str, **counts: int):
-    """Host span ``repro.<name>``; ``counts`` become the event's stats
-    (``span("serve.prefill", bucket=256, prompt=100)``).  More counts
-    can be added once known with ``set_metadata(**counts)``."""
+def span(name: str, **counts: int | str):
+    """Host span ``repro.<name>``; ``counts`` (numbers or labels) become
+    the event's stats (``span("serve.prefill", bucket=256, prompt=100,
+    mode="parallel")``).  More can be added once known with
+    ``set_metadata(**counts)``."""
     return jax.profiler.TraceAnnotation(ROOT + name, **counts)
 
 

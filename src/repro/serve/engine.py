@@ -11,9 +11,16 @@ host-side :class:`repro.serve.scheduler.Scheduler`:
   and the scatters donate the cache, so it is never held twice;
 * **bucketed prefill** — each admitted request prefills alone (B=1) in
   a jitted program compiled per *bucket length*, not per prompt length:
-  the prompt rides padded to its :class:`PromptBuckets` bucket and a
-  where-snapshot keeps only the state after exactly ``len(prompt)`` real
-  steps, so the padded prefill is bitwise-identical to an unpadded one;
+  the prompt rides padded to its :class:`PromptBuckets` bucket.  Where
+  the model has a parallel form (``Model.prefill``) the whole padded
+  prompt runs through the stack in one causal forward pass; padding
+  cannot reach a real row, since the causal mask keeps every real
+  position from attending a later one and the cache marks pad rows
+  ``pos = -1``, which decode never attends.  Other models (recurrent
+  state, ring buffers, MoE, encoder extras) step the prompt through
+  ``decode_step``, where a where-snapshot keeps only the state after
+  exactly ``len(prompt)`` real steps, bitwise what an unpadded prefill
+  gives;
 * **sliced decode** — between membership boundaries the engine runs one
   jitted :func:`repro.serve.decode.make_decode_slice` step (a
   ``while_loop`` of up to ``slice_len`` tokens with the psum-min EOS
@@ -135,6 +142,11 @@ class ServeEngine:
         # "set_tok"): steady-state steps leave it unchanged
         self.traces: collections.Counter[str] = collections.Counter()
         self._prefills: dict[Any, Callable] = {}  # bucket key -> jitted fn
+        self._prefill_mode = (
+            "stepwise" if model.prefill is None else "parallel"
+        )
+        # admissions prefilled, by mode ("parallel" or "stepwise")
+        self.prefills: collections.Counter[str] = collections.Counter()
         self._slice = self._build_slice()
         # stacked leaf rows have exactly the B=1 leaf's shape, so the
         # scatter is a plain per-row set on every leaf.  The compiled
@@ -232,16 +244,32 @@ class ServeEngine:
 
     def _prefill_fn(self, bucket_len: int):
         """Jitted B=1 bucketed prefill: ``(params, prompt (1, L), n_real,
-        extras or None) -> (cache, first token (1,))``.
+        extras or None) -> (cache, first token (1,))``, one compiled
+        trace per bucket length.
 
-        Teacher-forces the padded prompt through ``decode_step`` inside a
-        ``fori_loop``; a scalar ``keep = t < n_real`` where-snapshot on
-        (logits, cache) freezes the state after exactly ``n_real`` real
-        steps, so the result is bitwise what an unpadded prefill of the
-        true prompt produces — and there is exactly one compiled trace
-        per bucket length.
+        With ``model.prefill`` the padded prompt runs through the stack
+        in one causal forward pass: each layer writes K and V of all L
+        positions into the cache, marks positions ``>= n_real`` with
+        ``pos = -1`` and sets ``index = n_real``, and the head runs on
+        row ``n_real - 1`` alone.  No real row sees the padding (causal
+        mask here, ``pos = -1`` in decode); sums associate differently
+        from the token loop, so the result is close to it, not bitwise.
+
+        Without it, teacher-forces the padded prompt through
+        ``decode_step`` inside a ``fori_loop``; a scalar ``keep = t <
+        n_real`` where-snapshot on (logits, cache) freezes the state
+        after exactly ``n_real`` real steps, so the result is bitwise
+        what an unpadded prefill of the true prompt produces.
         """
         model, max_len, traces = self.model, self.max_len, self.traces
+
+        if model.prefill is not None:
+            def prefill(params, prompt, n_real, extras):
+                traces[f"prefill/{bucket_len}"] += 1
+                cache, logits = model.prefill(params, prompt, n_real, max_len)
+                return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+            return jax.jit(prefill)
 
         def prefill(params, prompt, n_real, extras):
             traces[f"prefill/{bucket_len}"] += 1
@@ -328,7 +356,8 @@ class ServeEngine:
         *finished* during this step.  No-op (returns ``[]``) when idle.
 
         Host spans: ``serve.admit`` (holding a ``serve.prefill`` with
-        counts ``bucket`` and ``prompt`` per admission), ``serve.slice``
+        counts ``bucket`` and ``prompt`` and the ``mode``, ``"parallel"``
+        or ``"stepwise"``, per admission), ``serve.slice``
         (the dispatch), ``serve.wait`` (until the slice's tokens are on
         the host) and ``serve.record`` (counts ``steps`` and ``tokens``).
         """
@@ -339,8 +368,10 @@ class ServeEngine:
                     "serve.prefill",
                     bucket=req.bucket_len,
                     prompt=len(req.prompt),
+                    mode=self._prefill_mode,
                 ):
                     cache_b1, tok0 = self._prefill(req)
+                self.prefills[self._prefill_mode] += 1
                 row = jnp.asarray(req.slot, jnp.int32)
                 self._cache = self._scatter(self._cache, cache_b1, row)
                 self._tok, self._active = self._set_tok(

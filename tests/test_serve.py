@@ -608,6 +608,38 @@ def test_engine_bitwise_matches_serial_serve_batch(arch):
     )
 
 
+def test_engine_steps_the_prompt_where_the_model_has_no_parallel_prefill():
+    # a recurrent state has no parallel form here: the engine keeps the
+    # token loop, whose tokens equal the fixed-batch serial driver's
+    import jax
+    import numpy as np
+
+    from repro.configs import get_config, reduced
+    from repro.launch.serve import serve_batch
+    from repro.models import build_model
+    from repro.serve import PromptBuckets, ServeEngine
+
+    model = build_model(reduced(get_config("rwkv6-1.6b")))
+    assert model.prefill is None
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    prompts = np.array([[3, 1, 4], [1, 5, 9], [2, 6, 5]], np.int32)
+    gen = 5
+    ref = np.asarray(
+        serve_batch(
+            model, params, jax.numpy.asarray(prompts),
+            gen_len=gen, max_len=16,
+        )
+    )
+    engine = ServeEngine(
+        model, params, num_slots=2, max_len=16,
+        buckets=PromptBuckets([8]),
+    )
+    reqs = [engine.submit(list(p), gen) for p in prompts]
+    out = engine.run()
+    assert [out[r.rid] for r in reqs] == ref.tolist()
+    assert engine.prefills == {"stepwise": len(prompts)}
+
+
 def test_engine_eos_early_finish():
     import jax
 

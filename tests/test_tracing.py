@@ -5,6 +5,8 @@
   ``serve.prefill`` of each admission, then ``serve.slice``,
   ``serve.wait`` and ``serve.record`` follow, with their counts.
 * ``ServeEngine.traces``: warmed-up steps trace nothing.
+* ``ServeEngine.prefills`` and the ``mode`` of ``serve.prefill``: a
+  model with ``Model.prefill`` (minicpm-2b) prefills in parallel.
 * The persistent compile cache (``enable_compile_cache``) hands back
   executables that carry their own program's scopes.
 * Device scopes, read from the ``op_name`` of compiled programs: NAP's
@@ -177,6 +179,26 @@ def test_engine_traces_unchanged_by_steady_state_steps():
     for p in ([2, 7], [1, 8, 2, 8, 1], [4, 5, 9], [6, 6, 6, 6, 6, 6, 6]):
         engine.submit(p, 4)
     engine.run()
+    assert dict(engine.traces) == warm
+
+
+def test_minicpm_engine_prefills_in_one_parallel_pass(tmp_path):
+    engine = _engine()
+    prompts = [[3, 1, 4], [1, 5, 9, 2, 6], [5, 3], [2, 7, 1, 8, 2, 8]]
+    for p in prompts[:2]:
+        engine.submit(p, 2)
+    engine.run()  # warm-up: both buckets' prefills
+    warm = dict(engine.traces)
+    with jax.profiler.trace(str(tmp_path)):
+        for p in prompts[2:]:
+            engine.submit(p, 2)
+        engine.run()
+    assert engine.prefills == {"parallel": len(prompts)}
+    prefills = [e for e in _repro_events(tmp_path)
+                if e[0] == "repro.serve.prefill"]
+    assert len(prefills) == 2
+    assert all(stats["mode"] == "parallel" for *_, stats in prefills)
+    assert engine.traces["prefill/4"] == engine.traces["prefill/8"] == 1
     assert dict(engine.traces) == warm
 
 
